@@ -1,54 +1,56 @@
-# Tier-1 verification + benchmark entry points.  Everything runs on CPU
-# (Pallas kernels in interpret mode); on a TPU host the same commands use
-# the compiled kernels automatically.
+# Tier-1 verification + benchmark entry points.  The tests run on the CPU
+# (JAX_PLATFORMS=cpu: Pallas kernels in interpret mode, plus compile-only
+# rehearsals for a v5e in tests/test_tpu_compile.py).  The chip run is
+# `python chip_smoke.py [--chips 4]` on a TPU host.
 
 PYTHON ?= python
+PYTEST = JAX_PLATFORMS=cpu $(PYTHON) -m pytest
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
 .PHONY: test test-fast test-serve test-quant test-exec test-step test-server test-chaos test-autotune test-mixed tune bench-kernels bench-stream bench-quant bench-exec bench-step bench-server bench-autotune bench-mixed bench
 
 test:
-	$(PYTHON) -m pytest -x -q
+	$(PYTEST) -x -q
 
 # skip the slow end-to-end training test
 test-fast:
-	$(PYTHON) -m pytest -x -q --deselect tests/test_gw_e2e.py
+	$(PYTEST) -x -q --deselect tests/test_gw_e2e.py
 
 # the stateful streaming serving path (equivalence, cache, donation)
 test-serve:
-	$(PYTHON) -m pytest -x -q tests/test_serve_streaming.py
+	$(PYTEST) -x -q tests/test_serve_streaming.py
 
 # the quantized packed-weight fused stack (grid, kernel, cache, serving)
 test-quant:
-	$(PYTHON) -m pytest -x -q tests/test_quant_stack.py
+	$(PYTEST) -x -q tests/test_quant_stack.py
 
 # the plan/bind/execute API (plans, executors, sharded fused wavefront)
 test-exec:
-	$(PYTHON) -m pytest -x -q tests/test_executor.py
+	$(PYTEST) -x -q tests/test_executor.py
 
 # the low-latency step kernel + multi-stream coalescing (bitwise contract)
 test-step:
-	$(PYTHON) -m pytest -x -q tests/test_step_kernel.py
+	$(PYTEST) -x -q tests/test_step_kernel.py
 
 # the continuous-batching stream server (deadline coalescer, backpressure,
 # scheduler determinism, latency histogram)
 test-server:
-	$(PYTHON) -m pytest -x -q tests/test_stream_server.py
+	$(PYTEST) -x -q tests/test_stream_server.py
 
 # the fault-injection suite (glitch quarantine, engine faults + watchdog,
 # snapshot/restore, scheduler supervision, close-vs-batch race)
 test-chaos:
-	$(PYTHON) -m pytest -x -q tests/test_chaos.py
+	$(PYTEST) -x -q tests/test_chaos.py
 
 # the autotune subsystem (knob spaces, tuned-plan cache, cached planning,
 # sweep harness, roofline model, HLO custom-call costs)
 test-autotune:
-	$(PYTHON) -m pytest -x -q tests/test_autotune.py
+	$(PYTEST) -x -q tests/test_autotune.py
 
 # the heterogeneous mixed backend (per-layer storage splits, segment
 # chaining bit-equality, balancer, act_bits, tuned split, mixed serving)
 test-mixed:
-	$(PYTHON) -m pytest -x -q tests/test_mixed_stack.py
+	$(PYTEST) -x -q tests/test_mixed_stack.py
 
 # measure the standard smoke grid on THIS machine and populate the
 # tuned-plan cache (runs/autotune/tuned.json) that `--tune cached` serving

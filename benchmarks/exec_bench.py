@@ -18,17 +18,16 @@ Four claims the execution API must keep true, as rows in the shared
   (executor as a jit pytree argument) measured 1.456x
   (``exec.dispatch_ratio``); a bound step that re-grows a dispatch tax
   regresses the serving hot path.
-* ``exec.sharded_wavefront_us`` — the ``fused_stack_sharded`` backend on a
-  2-device CPU mesh (subprocess, like tests/test_pipeline.py) alongside the
-  local fused backend, gated on bit-equality.  Interpret-mode timings are
-  correctness-grade; on real hardware the sharded win is VMEM capacity and
-  per-stage weight residency, not CPU wall clock.
+* ``exec.sharded_wavefront_us`` — the ``fused_stack_sharded`` backend on
+  the devices present (in-process; one device gives a 1-stage mesh)
+  alongside the local fused backend, gated on bit-equality.
+  Interpret-mode timings are correctness-grade; on real hardware the
+  sharded win is VMEM capacity and per-stage weight residency, not CPU
+  wall clock.
 """
 
 from __future__ import annotations
 
-import subprocess
-import sys
 import time
 
 import jax
@@ -51,67 +50,34 @@ def _timeit(f, *a, n=20):
     return (time.perf_counter() - t0) / n * 1e6
 
 
-_SHARDED_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-import time
-import jax, jax.numpy as jnp, numpy as np
-from repro.core.executor import plan_stack
-from repro.core.lstm import LstmConfig, init_lstm
+def _sharded_row(cfgs, params, xs) -> tuple:
+    """The sharded backend on the devices this process holds, in-process:
+    a JAX child started after this process touched JAX could not reach an
+    accelerator the parent already holds.  With one device the stage mesh
+    is the degenerate 1-stage one (still routed through shard_map)."""
+    import numpy as np
 
-dims = [(1, 32), (32, 32), (32, 32), (32, 32)]
-cfgs = [LstmConfig(in_dim=a, hidden=b) for a, b in dims]
-keys = jax.random.split(jax.random.PRNGKey(0), len(dims))
-params = [init_lstm(k, c) for k, c in zip(keys, cfgs)]
-xs = jax.random.normal(jax.random.PRNGKey(1), (8, 100, 1))
-
-local = plan_stack(cfgs, impl="fused_stack").bind(params)
-sharded = plan_stack(cfgs, impl="fused_stack", placement="sharded").bind(params)
-run_ex = jax.jit(lambda ex, x: ex(x, return_state=False))
-
-def timeit(ex, n=5):
-    jax.block_until_ready(run_ex(ex, xs))
-    t0 = time.perf_counter()
-    for _ in range(n):
-        out = run_ex(ex, xs)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / n * 1e6
-
-us_l = timeit(local)
-us_s = timeit(sharded)
-equal = int((np.asarray(run_ex(sharded, xs)) == np.asarray(run_ex(local, xs))).all())
-print(f"SHARDED_ROW us_sharded={us_s:.1f} us_local={us_l:.1f} equal={equal}")
-"""
-
-
-def _sharded_row() -> tuple:
-    import os
-
-    from repro.launch.subproc import child_env
-
-    r = subprocess.run(
-        [sys.executable, "-c", _SHARDED_SCRIPT],
-        capture_output=True, text=True, timeout=600, env=child_env(),
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    local = plan_stack(cfgs, impl="fused_stack").bind(params)
+    sharded = plan_stack(
+        cfgs, impl="fused_stack", placement="sharded"
+    ).bind(params)
+    n_stages = sharded.plan.mesh.shape["stage"]
+    run_ex = jax.jit(lambda ex, x: ex(x, return_state=False))
+    us_l = _timeit(run_ex, local, xs, n=5)
+    us_s = _timeit(run_ex, sharded, xs, n=5)
+    equal = int(
+        (np.asarray(run_ex(sharded, xs)) == np.asarray(run_ex(local, xs))).all()
     )
-    line = next(
-        (ln for ln in r.stdout.splitlines() if ln.startswith("SHARDED_ROW")),
-        None,
-    )
-    if line is None:
-        raise RuntimeError(
-            f"sharded wavefront subprocess produced no row: {r.stderr[-2000:]}"
-        )
-    kv = dict(tok.split("=") for tok in line.split()[1:])
-    us_s, us_l, equal = float(kv["us_sharded"]), float(kv["us_local"]), int(kv["equal"])
-    print(f"fused_stack_sharded (2-dev CPU mesh, 4L W32 T100): {us_s:.0f}us "
-          f"vs local fused {us_l:.0f}us, bit-equal={'OK' if equal else 'FAIL'}")
+    platform = jax.devices()[0].platform
+    print(f"fused_stack_sharded ({n_stages}-stage {platform} mesh, 4L W32 "
+          f"T100): {us_s:.0f}us vs local fused {us_l:.0f}us, "
+          f"bit-equal={'OK' if equal else 'FAIL'}")
     if not equal:  # hard gate: the sharded backend must match local exactly
         raise RuntimeError(
             "fused_stack_sharded diverged from the local fused backend"
         )
     return ("exec.sharded_wavefront_us", us_s,
-            f"local={us_l:.0f}us|equal={equal}")
+            f"local={us_l:.0f}us|stages={n_stages}|equal={equal}")
 
 
 def run() -> list[tuple]:
@@ -187,7 +153,7 @@ def run() -> list[tuple]:
             f"{packs_steady}x — the bind-once contract regressed"
         )
 
-    rows.append(_sharded_row())
+    rows.append(_sharded_row(cfgs, params, xs))
     return rows
 
 

@@ -219,7 +219,11 @@ def decode(
     out = executor(h_seq, initial_state, return_state=return_state)
     h_seq, finals = out if return_state else (out, None)
     # ---- TimeDistributed dense head ----------------------------------------
-    rec = h_seq.astype(cfg.dtype) @ params["dense"]["w"] + params["dense"]["b"]
+    # full precision: on the TPU a default fp32 dot is one bf16 pass
+    rec = jnp.dot(
+        h_seq.astype(cfg.dtype), params["dense"]["w"],
+        precision=jax.lax.Precision.HIGHEST,
+    ) + params["dense"]["b"]
     return (rec, finals) if return_state else rec
 
 

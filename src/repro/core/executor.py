@@ -54,6 +54,7 @@ from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from .backends import (
     BackendSpec,
@@ -261,7 +262,9 @@ def _default_stage_mesh(n_layers: int):
     n = max(1, min(len(jax.devices()), n_layers))
     while n > 1 and n_layers % n:
         n -= 1
-    return jax.make_mesh((n,), ("stage",))
+    # Auto axes: the sharded backends rely on GSPMD to place the operands
+    # and to slice shard_map's output, which Explicit axes refuse
+    return jax.make_mesh((n,), ("stage",), axis_types=(AxisType.Auto,))
 
 
 @functools.lru_cache(maxsize=128)

@@ -171,8 +171,7 @@ def wavefront_shard_map(
     axis: str = "stage",
 ) -> jax.Array:
     """Same schedule with stages on devices and ppermute hand-off."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import PartitionSpec as P
 
     n_stages = mesh.shape[axis]
     b, t, d_max = xs.shape
@@ -208,12 +207,12 @@ def wavefront_shard_map(
         _, outs = jax.lax.scan(tick, (h0, c0, inbox0), jnp.arange(n_ticks))
         return outs[None]  # (1, ticks, B, ct, H)
 
-    out_ticks = shard_map(
+    out_ticks = jax.shard_map(
         program,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )(stacked, xs)
     # take the last stage's outputs, drop the fill ticks
     valid = out_ticks[-1, n_stages - 1:]
@@ -247,13 +246,13 @@ def wavefront_shard_map_fused(
     ever crosses devices.
 
     Bit-for-bit equal to the local ``fused_stack`` backend (tested on a
-    CPU mesh): chunked sub-stack execution performs the identical per-step
+    CPU mesh; ``chip_smoke.py --chips 4`` checks the GW nominal scores on
+    four v5e chips): chunked sub-stack execution performs the identical per-step
     math in the identical order; only *where* each (layer, chunk) cell
     evaluates changes.  Returns (hs_last (B, T, W), h_final (L, B, W),
     c_final fp32 (L, B, W)).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.kernels.lstm_stack.ops import lstm_stack_op
 
@@ -299,12 +298,12 @@ def wavefront_shard_map_fused(
         )
         return outs[None], h, c  # (1, ticks, B, ct, W), (L/S, B, W) x2
 
-    out_ticks, h_f, c_f = shard_map(
+    out_ticks, h_f, c_f = jax.shard_map(
         program,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()),
         out_specs=(P(axis), P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )(packed.stacked, h0, c0, xs_p)
     valid = out_ticks[-1, n_stages - 1:]
     return jnp.moveaxis(valid, 0, 1).reshape(b, t, w), h_f, c_f

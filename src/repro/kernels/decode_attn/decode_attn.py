@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 
 _NEG_INF = -1e30
 
@@ -131,7 +130,7 @@ def decode_attn(
             pltpu.VMEM((hq, 1), jnp.float32),
             pltpu.VMEM((hq, d), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

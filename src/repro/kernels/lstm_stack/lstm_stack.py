@@ -57,7 +57,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
+
+def dot_precision(compute_dtype) -> jax.lax.Precision | None:
+    """Contraction precision for the fused stack's matmuls (both kernels and
+    the out-of-kernel layer-0 projection): full fp32 at an fp32 compute
+    dtype.  XLA's TPU default for fp32 operands is one bf16 pass, which
+    would make fp32 weight storage numerically a bf16 model.  bf16 operands
+    are exact in one pass, and Mosaic refuses fp32 precision for them.  The
+    CPU ignores the setting."""
+    if jnp.dtype(compute_dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return None
 
 
 def _lstm_stack_kernel(
@@ -83,6 +93,7 @@ def _lstm_stack_kernel(
     act_quant: Callable | None,
 ):
     s = pl.program_id(1)
+    prec = dot_precision(h_scr.dtype)
 
     @pl.when(s == 0)
     def _init():
@@ -116,11 +127,13 @@ def _lstm_stack_kernel(
                     h_scr[layer - 1],
                     load_w(wx_ref, layer),
                     preferred_element_type=jnp.float32,
+                    precision=prec,
                 )
             hh = jnp.dot(
                 h_scr[layer],
                 load_w(wh_ref, layer),
                 preferred_element_type=jnp.float32,
+                precision=prec,
             )
             # per-gate tail: each 4W-slice scales its own fp32 accumulator
             # ((h @ q) * s, per gate) BEFORE the gate sum — layers whose
@@ -128,6 +141,8 @@ def _lstm_stack_kernel(
             # Slicing first commutes with the elementwise scale/bias ops,
             # so uniform (broadcast) scales reproduce the historical
             # whole-accumulator order bit-for-bit.
+            # the bias is loaded per gate from the ref: Mosaic refuses to
+            # broadcast a lane slice of a loaded (1, 4W) row to the block
             pre = []
             for g in range(4):
                 sl = slice(g * width, (g + 1) * width)
@@ -135,7 +150,7 @@ def _lstm_stack_kernel(
                 if layer > 0:
                     if quantized:
                         gxg = gxg * scale_ref[layer, 0, g]
-                    gxg = gxg + b_ref[layer][:, sl]
+                    gxg = gxg + b_ref[layer, :, sl]
                 hhg = hh[:, sl]
                 if quantized:
                     hhg = hhg * scale_ref[layer, 1, g]
@@ -280,7 +295,7 @@ def lstm_stack(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         # operands: (xw0, w_x, w_h, b, scales, h0, c0); outputs: (hs, h_f, c_f)

@@ -54,7 +54,7 @@ from repro.kernels.lstm_scan.ops import (
     choose_blocking,
 )
 
-from .lstm_stack import lstm_stack
+from .lstm_stack import dot_precision, lstm_stack
 
 #: weight storage dtype -> the jnp dtype the packed arrays must hold
 _WEIGHT_JNP = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
@@ -195,7 +195,13 @@ def lstm_stack_op(
     w0 = stacked["w_x"][0]
     if w0.dtype != xs_p.dtype:
         w0 = w0.astype(xs_p.dtype)
-    xw0 = (xs_p @ w0).astype(jnp.float32)
+    # the accumulator is rounded to the compute dtype before widening; the
+    # explicit round trip keeps XLA from folding the widen into the dot,
+    # which would skip the bf16 rounding the step kernel performs
+    xw0 = jnp.dot(
+        xs_p, w0, preferred_element_type=jnp.float32,
+        precision=dot_precision(xs_p.dtype),
+    ).astype(xs_p.dtype).astype(jnp.float32)
     if quantized:
         scales = normalize_scales(stacked["scales"], stacked["w_h"].shape[0])
         xw0 = apply_gate_scales(xw0, scales[0, 0])

@@ -17,9 +17,9 @@ This kernel is the step-scale specialization, for ``T in {1..chunk_len}``:
   kernel invocation: one compiled cell body iterated over ``t`` with
   per-layer ``h``/``c`` carried as *values* (no stage-axis scratch, no
   ``pl.when`` masking, no revisited output blocks);
-* **layer 0's input projection happens in-kernel** — the raw ``(B, T, W)``
-  chunk is the only streamed input; nothing the size of the gate tensor
-  ever leaves the chip;
+* **layer 0's input projection happens in-kernel** — the raw chunk
+  (time-major ``(T, B, W)`` inside the kernel) is the only streamed input;
+  nothing the size of the gate tensor ever leaves the chip;
 * **optionally one fused gate matmul per cell** (``fuse_gates``): the two
   gate MVMs become a single ``[x_or_h_prev ; h_l] @ [W_x ; W_h]``
   ``(Bb, 2W) @ (2W, 4W)`` MXU issue — halving matmul issues exactly where
@@ -38,7 +38,9 @@ bitwise; any FIXED chunking replays bit-identically, which is what the
 ``push_many`` == sequential-replay equality builds on.
 ``fuse_gates=True`` additionally reorders the gate sum's reduction (one
 contraction over ``2W`` instead of two over ``W``); it is the default on
-compiled TPU backends, where the MXU issue-rate argument applies.
+compiled TPU backends, where the MXU issue-rate argument applies.  There,
+``chip_smoke.py`` checks that served GW window scores still equal the
+batch (wavefront) scores bit for bit at chunk lengths 1, 25 and 100.
 Quantized (int8) packs always use the separate-dot path: ``s_x`` and
 ``s_h`` scale two different fp32 accumulators, which a fused contraction
 would mix.
@@ -55,9 +57,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import EXACT, kernel_safe, make_act_quant
-from repro.kernels._compat import compiler_params
 from repro.kernels.lstm_scan.ops import _on_cpu, choose_blocking
 
+from .lstm_stack import dot_precision
 from .ops import check_packed_weight_dtype, normalize_scales
 
 #: hard ceiling on T*L cell updates per call: the step kernel executes the
@@ -68,14 +70,14 @@ MAX_STEP_UNROLL = 512
 
 
 def _lstm_stack_step_kernel(
-    x_ref,      # (Bb, T, W)    raw layer-0 chunk, compute dtype
+    x_ref,      # (T, Bb, W)    raw layer-0 chunk, time-major, compute dtype
     wx_ref,     # (L, W, 4W)    VMEM-resident input projections
     wh_ref,     # (L, W, 4W)    VMEM-resident recurrent weights
     b_ref,      # (L, 1, 4W)    fp32 biases
     scale_ref,  # (L, 2, 4)     fp32 SMEM per-gate [s_x, s_h] dequant scales
     h0_ref,     # (L, Bb, W)    initial hidden per layer
     c0_ref,     # (L, Bb, W)    initial cell per layer (fp32)
-    hs_ref,     # out: (Bb, T, W) last layer's hidden chunk
+    hs_ref,     # out: (T, Bb, W) last layer's hidden chunk, time-major
     hf_ref,     # out: (L, Bb, W) final hidden per layer
     cf_ref,     # out: (L, Bb, W) final cell per layer (fp32)
     *,
@@ -89,6 +91,7 @@ def _lstm_stack_step_kernel(
     act_quant: Callable | None,
 ):
     compute = h0_ref.dtype
+    prec = dot_precision(compute)
 
     def load_w(w_ref, layer):
         w = w_ref[layer]
@@ -106,17 +109,6 @@ def _lstm_stack_step_kernel(
             jnp.concatenate([load_w(wx_ref, layer), load_w(wh_ref, layer)], axis=0)
             for layer in range(n_layers)
         ]
-    else:
-        # layer 0's input projection over the WHOLE chunk, one matmul —
-        # structurally the wavefront path's out-of-kernel mvm_x, minus its
-        # HBM round-trip.  Hoisting matters for bitwise reproducibility
-        # too: left as T per-step dots over the same weight, XLA merges
-        # the independent dots into one differently-shaped contraction
-        # and the summation order shifts.  The matmul runs at the compute
-        # dtype and is only then widened (bf16 rounds the accumulator
-        # exactly like ``(xs @ w0).astype(f32)`` outside), keeping this
-        # kernel bit-for-bit against lstm_stack under every dtype.
-        gx0_all = (x_ref[...] @ load_w(wx_ref, 0)).astype(jnp.float32)
 
     def cell(t, h, c):
         """One timestep over all layers (ascending: layer l consumes
@@ -124,30 +116,37 @@ def _lstm_stack_step_kernel(
         h, c = list(h), list(c)
         for layer in range(n_layers):
             if fuse_gates:
-                x_in = (
-                    jax.lax.dynamic_index_in_dim(
-                        x_ref[...], t, axis=1, keepdims=False
-                    )
-                    if layer == 0 else h[layer - 1]
-                )
+                x_in = x_ref[t] if layer == 0 else h[layer - 1]
                 hcat = jnp.concatenate([x_in, h[layer]], axis=1)
                 gx = jnp.dot(
-                    hcat, w_cat[layer], preferred_element_type=jnp.float32
+                    hcat, w_cat[layer], preferred_element_type=jnp.float32,
+                    precision=prec,
                 )
                 hh = None
             else:
                 if layer == 0:
-                    gx = jax.lax.dynamic_index_in_dim(
-                        gx0_all, t, axis=1, keepdims=False
-                    )
+                    # layer 0's input projection: a ref-level load of
+                    # timestep t (Mosaic lowers no value-level dynamic
+                    # slice).  The accumulator is rounded to the compute
+                    # dtype before widening, as the wavefront path's
+                    # out-of-kernel ``(xs @ w0).astype(f32)`` rounds it; the
+                    # explicit round trip keeps XLA from folding the widen
+                    # into the dot (which would skip the bf16 rounding)
+                    gx = jnp.dot(
+                        x_ref[t], load_w(wx_ref, 0),
+                        preferred_element_type=jnp.float32,
+                        precision=prec,
+                    ).astype(compute).astype(jnp.float32)
                 else:
                     gx = jnp.dot(
                         h[layer - 1], load_w(wx_ref, layer),
                         preferred_element_type=jnp.float32,
+                        precision=prec,
                     )
                 hh = jnp.dot(
                     h[layer], load_w(wh_ref, layer),
                     preferred_element_type=jnp.float32,
+                    precision=prec,
                 )
             # per-gate tail: scale each 4W-slice on its own accumulator
             # BEFORE the gate sum (per-gate int8 grids), bias placement
@@ -158,7 +157,9 @@ def _lstm_stack_step_kernel(
                 gxg = gx[:, sl]
                 if quantized:
                     gxg = gxg * scale_ref[layer, 0, g]
-                gxg = gxg + b_ref[layer][:, sl]
+                # per-gate ref load, as in the wavefront kernel (Mosaic
+                # refuses to broadcast a lane slice of a loaded bias row)
+                gxg = gxg + b_ref[layer, :, sl]
                 if hh is not None:
                     hhg = hh[:, sl]
                     if quantized:
@@ -183,7 +184,7 @@ def _lstm_stack_step_kernel(
     if t_len == 1:
         # the serving-critical T=1 push: straight-line code, no loop
         h, c = cell(0, h, c)
-        hs_ref[:, 0, :] = h[n_layers - 1].astype(hs_ref.dtype)
+        hs_ref[0] = h[n_layers - 1].astype(hs_ref.dtype)
     else:
         # one compiled loop body iterated over t — NOT a python unroll.
         # Bitwise reproducibility again: T copies of the cell would give
@@ -194,9 +195,7 @@ def _lstm_stack_step_kernel(
         def body(t, carry):
             h, c = carry[:n_layers], carry[n_layers:]
             h, c = cell(t, h, c)
-            hs_ref[:, pl.dslice(t, 1), :] = h[n_layers - 1][:, None, :].astype(
-                hs_ref.dtype
-            )
+            hs_ref[t] = h[n_layers - 1].astype(hs_ref.dtype)
             return (*h, *c)
 
         out = jax.lax.fori_loop(0, t_len, body, (*h, *c))
@@ -230,10 +229,11 @@ def lstm_stack_step(
 
     Unlike ``lstm_stack`` the input is the *raw* chunk — layer 0's gate
     projection happens in-kernel, so no ``(T, B, 4W)`` tensor ever exists.
-    The chunk stays batch-major end to end (no time-major transpose on the
-    hot path).  ``alias_state`` maps h0/c0 onto the finals exactly like the
-    wavefront kernel, so a persistent-state serving loop carries (h, c)
-    with zero per-call state allocations.
+    Inside the kernel the chunk is time-major, so timestep ``t`` is a
+    leading-axis ref load; at the serving-critical T=1 the swap in and out
+    is a free reshape.  ``alias_state`` maps h0/c0 onto the finals exactly
+    like the wavefront kernel, so a persistent-state serving loop carries
+    (h, c) with zero per-call state allocations.
     """
     batch, t_len, w4 = xs.shape[0], xs.shape[1], 4 * xs.shape[2]
     width = xs.shape[2]
@@ -280,12 +280,12 @@ def lstm_stack_step(
         act_quant=act_quant,
     )
     out_shape = [
-        jax.ShapeDtypeStruct((batch, t_len, width), h0.dtype),        # hs
+        jax.ShapeDtypeStruct((t_len, batch, width), h0.dtype),        # hs
         jax.ShapeDtypeStruct((n_layers, batch, width), h0.dtype),     # h_f
         jax.ShapeDtypeStruct((n_layers, batch, width), jnp.float32),  # c_f
     ]
     in_specs = [
-        pl.BlockSpec((block_b, t_len, width), lambda b: (b, 0, 0)),
+        pl.BlockSpec((t_len, block_b, width), lambda b: (0, b, 0)),
         pl.BlockSpec((n_layers, width, w4), lambda b: (0, 0, 0)),
         pl.BlockSpec((n_layers, width, w4), lambda b: (0, 0, 0)),
         pl.BlockSpec((n_layers, 1, w4), lambda b: (0, 0, 0)),
@@ -295,22 +295,24 @@ def lstm_stack_step(
         pl.BlockSpec((n_layers, block_b, width), lambda b: (0, b, 0)),
     ]
     out_specs = [
-        pl.BlockSpec((block_b, t_len, width), lambda b: (b, 0, 0)),
+        pl.BlockSpec((t_len, block_b, width), lambda b: (0, b, 0)),
         pl.BlockSpec((n_layers, block_b, width), lambda b: (0, b, 0)),
         pl.BlockSpec((n_layers, block_b, width), lambda b: (0, b, 0)),
     ]
-    return pl.pallas_call(
+    hs, h_f, c_f = pl.pallas_call(
         kernel,
         grid=(n_b,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         # operands: (xs, w_x, w_h, b, scales, h0, c0); outputs: (hs, h_f, c_f)
         input_output_aliases={5: 1, 6: 2} if alias_state else {},
         interpret=interpret,
         name="lstm_stack_step",
-    )(xs, w_x, w_h, b.reshape(n_layers, 1, w4), scales, h0, c0)
+    )(jnp.swapaxes(xs, 0, 1), w_x, w_h, b.reshape(n_layers, 1, w4), scales,
+      h0, c0)
+    return jnp.swapaxes(hs, 0, 1), h_f, c_f
 
 
 @functools.partial(
@@ -337,8 +339,9 @@ def lstm_stack_step_op(
     """Step-path twin of ``lstm_stack_op`` for short chunks.
 
     Differences on the hot path: no out-of-kernel mvm_x (layer 0 projects
-    in-kernel from the raw chunk), no time-major transposes, and one grid
-    step per batch block.  Returns the same
+    in-kernel from the raw chunk), no gate-sized time-major transposes (the
+    raw chunk's is a reshape at T=1), and one grid step per batch block.
+    Returns the same
     (hs: (B, T, W), h_final: (L, B, W), c_final fp32) triple.
 
     ``fuse_gates=None`` resolves to the numerics contract documented in the

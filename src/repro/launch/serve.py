@@ -57,6 +57,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model
 from repro.serve.engine import LmEngine
 from repro.serve.latency import LatencyHistogram
@@ -162,6 +163,7 @@ def main():
                     help="restore the engine from a snapshot before "
                          "serving (fingerprint-checked; server mode)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.mode == "anomaly":
         return serve_anomaly(args)

@@ -1,12 +1,11 @@
 """Child-process environment for multi-device CPU-mesh smokes.
 
-Tests and benchmarks that need more than one device spawn a subprocess
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the flag must
-be set before jax imports).  The child env has to thread platform
-selection through: without e.g. ``JAX_PLATFORMS=cpu`` jax probes for
-accelerator plugins in the sandboxed child and can stall or hang (this bit
-test_pipeline/test_launch_sharding once — the dryrun smoke went
-472s -> 12s).  One helper so every spawning site threads the same vars.
+Tests that need several CPU devices spawn a subprocess with
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the flag must be
+set before jax imports).  The child env threads platform selection
+(``JAX_PLATFORMS``) through, so the child runs on the same backend as its
+parent.  Only for CPU meshes: on an accelerator host the parent already
+holds the chip, and a JAX child cannot reach it.
 """
 
 from __future__ import annotations

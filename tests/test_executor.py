@@ -312,7 +312,7 @@ for nc in (1, 2, 4, 8):
         np.asarray(p(xs, return_state=False)), ref)
 
 # plan-time divisibility error on a real 2-stage mesh
-mesh2 = jax.make_mesh((2,), ("stage",))
+mesh2 = jax.make_mesh((2,), ("stage",), axis_types=(jax.sharding.AxisType.Auto,))
 cfgs3 = cfgs[:3]
 try:
     plan_stack(cfgs3, impl="fused_stack", placement="sharded", mesh=mesh2)

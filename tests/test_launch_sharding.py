@@ -1,5 +1,6 @@
 """Sharding rule engine + mesh helpers + HLO analyzer unit tests."""
 
+import os
 import subprocess
 import sys
 
@@ -106,7 +107,8 @@ from repro.models.layers import ShardCtx
 from repro.train.optimizer import AdamWConfig, init_opt_state
 from repro.train.step import make_train_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = dataclasses.replace(get_arch("granite-3-2b").reduced(), vocab=512)
 api = get_model(cfg)
 shape = InputShape("smoke", seq_len=64, global_batch=8, kind="train")
@@ -149,6 +151,6 @@ class TestDryrunSmoke:
             [sys.executable, "-c", _DRYRUN_SMOKE],
             capture_output=True, text=True, timeout=600,
             env=child_env(),
-            cwd="/root/repo",
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         )
         assert "DRYRUN_SMOKE_OK" in r.stdout, (r.stdout[-500:], r.stderr[-2000:])

@@ -1,5 +1,6 @@
 """Wavefront pipeline == sequential stack execution (the paper's Fig. 7)."""
 
+import os
 import subprocess
 import sys
 
@@ -104,7 +105,7 @@ for p, c in zip(params, cfgs):
     ref, _ = lstm_forward(p, ref, c)
 
 stacked, width = pack_uniform(params, [d[0] for d in dims], [d[1] for d in dims])
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = jax.make_mesh((4,), ("stage",), axis_types=(jax.sharding.AxisType.Auto,))
 xs_p = jnp.pad(xs, ((0, 0), (0, 0), (0, width - 1)))
 out = wavefront_shard_map(stacked, xs_p, n_chunks=4, mesh=mesh)
 np.testing.assert_allclose(out[..., :8], ref, rtol=2e-5, atol=2e-5)
@@ -121,6 +122,6 @@ class TestShardMapWavefront:
             [sys.executable, "-c", _SHARD_MAP_SCRIPT],
             capture_output=True, text=True, timeout=300,
             env=child_env(),
-            cwd="/root/repo",
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         )
         assert "SHARD_MAP_OK" in r.stdout, r.stderr[-2000:]
