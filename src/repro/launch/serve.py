@@ -31,9 +31,16 @@ coalesces whatever is pending into ``push_many`` batches
 deadline with the self-tuning policy: per-bucket arrival-rate EWMAs pick
 a deadline that fills the batch with high probability (capped by
 ``--max-deadline-us``), flushing immediately when every joined stream is
-already pending or the batch cannot fill within the cap.  Enqueue->score
-latency lands in a fixed-bin histogram; the run prints p50/p99/max plus
-the scheduler's tick, flush, batch-fill, and drop counters.
+already pending or the batch cannot fill within the cap.  Per-chunk
+latency from enqueue to the end of its ``push_many`` lands in a fixed-bin
+histogram; the run prints p50/p99/max plus the scheduler's tick, flush,
+batch-fill, and drop counters.  Both anomaly loops end with the host time
+by stage: p50/p99 and count of each span of ``serve/telemetry.py`` (in
+server mode over the run: submit, queue wait, scheduling, tick, the
+engine's step, zero-state creation, window finish and its sync,
+delivery; without it, the calibration's ``score`` call and, with
+``--streams``, ``push_many``), with the zero states created and the
+programs built.
 ``--sanitize {off,reject,hold,reset}`` screens every submitted chunk for
 NaN/Inf (and ``--saturation-limit``) before it can enter a batch, with
 the chosen quarantine policy; ``--checkpoint PATH`` snapshots the engine
@@ -59,6 +66,7 @@ import numpy as np
 from repro.configs import get_arch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model
+from repro.serve import telemetry
 from repro.serve.engine import LmEngine
 from repro.serve.latency import LatencyHistogram
 
@@ -277,6 +285,7 @@ def serve_anomaly(args):
           f"{flagged} flagged; latency p50={hist.percentile(50):.0f}us "
           f"p99={hist.percentile(99):.0f}us "
           f"max={hist.max_us:.0f}us on this host")
+    print_stages()
 
 
 def serve_server(args, params, cfg, ds):
@@ -356,6 +365,7 @@ def serve_server(args, params, cfg, ds):
     for wid in warm_ids:
         engine.drop_stream(wid)
 
+    telemetry.reset()  # the stage line covers the run, not the warm-up
     t0 = time.perf_counter()
     with server:
         live = [i for i, q in enumerate(queues) if q]
@@ -379,9 +389,10 @@ def serve_server(args, params, cfg, ds):
           f"{dict(sorted(s.batch_fill.items()))}"
           + (f", effective width {server.effective_coalesce}"
              if args.adaptive else ""))
-    print(f"enqueue->score latency: p50={s.latency.percentile(50):.0f}us "
+    print(f"enqueue->push_many latency: p50={s.latency.percentile(50):.0f}us "
           f"p99={s.latency.percentile(99):.0f}us "
           f"max={s.latency.max_us:.0f}us over {s.latency.count} chunks")
+    print_stages()
     if health is not None:
         print(f"health: {s.rejected} rejected, {s.held} held, "
               f"{s.sanitize_resets} sanitize resets, "
@@ -392,6 +403,20 @@ def serve_server(args, params, cfg, ds):
               f"{s.scheduler_restarts} scheduler restarts, "
               f"{s.checkpoints} checkpoints"
               + (f" -> {args.checkpoint}" if args.checkpoint else ""))
+
+
+def print_stages() -> None:
+    """One line: the host time of each serving stage (p50/p99 us and
+    count, from ``serve/telemetry.py``) and the recorder's counters."""
+    snap = telemetry.snapshot()
+    stages = ", ".join(
+        f"{name} {st['p50_us']:.0f}/{st['p99_us']:.0f}us x{st['count']}"
+        for name, st in snap["spans"].items()
+    )
+    counters = snap["counters"]
+    print(f"host time by stage (p50/p99, count): {stages}; "
+          f"{counters.get('engine.states_created', 0)} zero states created, "
+          f"{counters.get('engine.programs_built', 0)} programs built")
 
 
 def print_plan(args, params, cfg) -> None:

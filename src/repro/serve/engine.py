@@ -54,6 +54,7 @@ from repro.core.backends import (  # noqa: F401  (re-exports)
 )
 from repro.kernels.lstm_scan.ops import SUBLANES
 from repro.models.api import get_model
+from repro.serve import telemetry
 from repro.serve.health import (
     SNAPSHOT_VERSION,
     check_fingerprint,
@@ -590,9 +591,16 @@ class StreamingAnomalyEngine:
     def _stream_slot(self, stream_id) -> _StreamSlot:
         slot = self._streams.get(stream_id)
         if slot is None:
-            slot = _StreamSlot(state=self._zero_state1_jit())
+            slot = _StreamSlot(state=self._new_state1())
             self._streams[stream_id] = slot
         return slot
+
+    def _new_state1(self):
+        """A fresh B=1 zero state (a new stream, a pad stream, or a window
+        that just finished without ``carry_state``)."""
+        with telemetry.span("engine.new_state"):
+            telemetry.count("engine.states_created")
+            return self._zero_state1_jit()
 
     def _coalesced_step(self, n: int):
         """One jitted gather->step->scatter for an ``n``-stream pool.
@@ -607,6 +615,7 @@ class StreamingAnomalyEngine:
         """
         fn = self._coalesce_jits.get(n)
         if fn is None:
+            telemetry.count("engine.programs_built")
             ax = self._state_batch_axis()
             exec_enc = self._exec_enc
 
@@ -651,52 +660,58 @@ class StreamingAnomalyEngine:
         its window is still filling).  Requires ``batch == 1`` — the
         lock-step ``push`` axis and the coalescing pool do not mix.
         """
-        if self.batch != 1:
-            raise ValueError(
-                "push_many coalesces independent B=1 streams; construct the "
-                f"engine with batch=1 (got batch={self.batch})"
-            )
-        ids = list(stream_ids)
-        if len(set(ids)) != len(ids):
-            raise ValueError("push_many: duplicate stream ids in one call")
-        chunks = np.asarray(chunks)
-        if (
-            chunks.ndim != 3
-            or chunks.shape[0] != len(ids)
-            or chunks.shape[2] != self.cfg.input_dim
-        ):
-            raise ValueError(
-                f"chunks must be (n_streams={len(ids)}, t, "
-                f"{self.cfg.input_dim}), got {chunks.shape}"
-            )
-        slots = [self._stream_slot(sid) for sid in ids]
-        out: dict = {sid: [] for sid in ids}
-        step_n = self._coalesced_step(len(slots))
-        pos, t_total = 0, chunks.shape[1]
-        while pos < t_total:
-            take = min(
-                t_total - pos, min(self.window - s.filled for s in slots)
-            )
-            piece = np.array(chunks[:, pos : pos + take])
-            # gather -> one B=N step -> scatter, compiled as one call: the
-            # per-piece host cost no longer scales with the pool size (the
-            # numpy piece transfers inside the jit — no eager device_put)
-            new_states = step_n(piece, tuple(s.state for s in slots))
-            for i, slot in enumerate(slots):
-                slot.state = new_states[i]
-                slot.chunks.append(piece[i : i + 1])
-                slot.filled += take
-            pos += take
-            done = [
-                (sid, s) for sid, s in zip(ids, slots)
-                if s.filled == self.window
-            ]
-            if done:
-                for (sid, _), score in zip(
-                    done, self._finish_streams([s for _, s in done])
-                ):
-                    out[sid].append(score)
-        return out
+        with telemetry.span("engine.push_many"):
+            if self.batch != 1:
+                raise ValueError(
+                    "push_many coalesces independent B=1 streams; construct "
+                    f"the engine with batch=1 (got batch={self.batch})"
+                )
+            ids = list(stream_ids)
+            if len(set(ids)) != len(ids):
+                raise ValueError("push_many: duplicate stream ids in one call")
+            chunks = np.asarray(chunks)
+            if (
+                chunks.ndim != 3
+                or chunks.shape[0] != len(ids)
+                or chunks.shape[2] != self.cfg.input_dim
+            ):
+                raise ValueError(
+                    f"chunks must be (n_streams={len(ids)}, t, "
+                    f"{self.cfg.input_dim}), got {chunks.shape}"
+                )
+            slots = [self._stream_slot(sid) for sid in ids]
+            out: dict = {sid: [] for sid in ids}
+            step_n = self._coalesced_step(len(slots))
+            pos, t_total = 0, chunks.shape[1]
+            while pos < t_total:
+                # one engine.step span per piece: the piece copy and the
+                # step's dispatch with its host-to-device copy
+                with telemetry.span("engine.step"):
+                    take = min(
+                        t_total - pos,
+                        min(self.window - s.filled for s in slots),
+                    )
+                    piece = np.array(chunks[:, pos : pos + take])
+                    # gather -> one B=N step -> scatter, compiled as one
+                    # call: the per-piece host cost no longer scales with
+                    # the pool size (the numpy piece transfers inside the
+                    # jit — no eager device_put)
+                    new_states = step_n(piece, tuple(s.state for s in slots))
+                    for i, slot in enumerate(slots):
+                        slot.state = new_states[i]
+                        slot.chunks.append(piece[i : i + 1])
+                        slot.filled += take
+                pos += take
+                done = [
+                    (sid, s) for sid, s in zip(ids, slots)
+                    if s.filled == self.window
+                ]
+                if done:
+                    for (sid, _), score in zip(
+                        done, self._finish_streams([s for _, s in done])
+                    ):
+                        out[sid].append(score)
+            return out
 
     def _finish_fn(self, n: int):
         """One jitted gather->latent->pad->decode->score per done-group
@@ -715,6 +730,7 @@ class StreamingAnomalyEngine:
         """
         fn = self._finish_jits.get(n)
         if fn is None:
+            telemetry.count("engine.programs_built")
             ax = self._state_batch_axis()
             exec_enc, exec_dec, cfg = self._exec_enc, self._exec_dec, self.cfg
             pad = _pad_width(n) - n
@@ -748,18 +764,20 @@ class StreamingAnomalyEngine:
         decode for the whole group (bit-equal to per-stream scoring: the
         decode + MSE tail is row-independent)."""
         k = len(slots)
-        xs = np.concatenate(
-            [np.concatenate(s.chunks, axis=1) for s in slots], axis=0
-        )
-        scores = np.asarray(
-            self._finish_fn(k)(
+        with telemetry.span("engine.finish"):
+            xs = np.concatenate(
+                [np.concatenate(s.chunks, axis=1) for s in slots], axis=0
+            )
+            scores = self._finish_fn(k)(
                 self.params, tuple(s.state for s in slots), xs
             )
-        )[:k]
+            # the wait for the decode on the device and the copy back
+            with telemetry.span("engine.finish_sync"):
+                scores = np.asarray(scores)[:k]
         for slot in slots:
             slot.chunks, slot.filled = [], 0
             if not self.carry_state:
-                slot.state = self._zero_state1_jit()
+                slot.state = self._new_state1()
         return [scores[i : i + 1] for i in range(k)]
 
     def _latent(self) -> jax.Array:
@@ -792,13 +810,24 @@ class StreamingAnomalyEngine:
 
     def score(self, windows: np.ndarray) -> np.ndarray:
         """One-shot batch scoring on the same pre-bound executors (does not
-        touch stream state); equals chunked scoring to fp tolerance."""
-        return np.asarray(
-            self._score_batch(
-                self.params, self._exec_enc, self._exec_dec,
-                jnp.asarray(windows),
+        touch stream state); equals chunked scoring to fp tolerance.
+
+        Spans: ``engine.score`` the call, inside it ``engine.score_put``
+        (the batch's copy to the device) and ``engine.score_sync`` (the
+        wait for the result and its copy back); ``engine.score_host``
+        records the call less that wait: the host's own time in the call,
+        in which a caller that waits for each call leaves the device idle.
+        """
+        with telemetry.span("engine.score") as call:
+            with telemetry.span("engine.score_put"):
+                x = jnp.asarray(windows)
+            scores = self._score_batch(
+                self.params, self._exec_enc, self._exec_dec, x
             )
-        )
+            with telemetry.span("engine.score_sync") as sync:
+                scores = np.asarray(scores)
+        telemetry.record("engine.score_host", call.seconds - sync.seconds)
+        return scores
 
     def flag(self, windows: np.ndarray) -> np.ndarray:
         return self.score(windows) > self.threshold

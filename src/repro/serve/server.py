@@ -41,10 +41,15 @@ continuous-batching loop LLM serving uses:
 * **dynamic lifecycle** — streams join on first submit and leave via
   ``close_stream``; the engine's slot gather/scatter is already
   backend-native, so join/leave is host-side bookkeeping only;
-* **first-class metrics** — per-chunk enqueue->score latency lands in a
-  ``LatencyHistogram`` (p50/p99/max are results, not printf), plus tick
-  counts, the batch-fill distribution, deadline-vs-full flush counts, and
-  drops.
+* **first-class metrics** — per-chunk latency from enqueue to the end of
+  its ``push_many`` lands in a ``LatencyHistogram`` (p50/p99/max are
+  results, not printf): for a chunk that completes a window that includes
+  the window's decode and score read-back, for any other chunk only the
+  step's dispatch.  Plus tick counts, the batch-fill distribution,
+  deadline-vs-full flush counts, and drops.  Each stage of a tick is a
+  span of ``serve/telemetry.py`` (``serve.submit``, ``serve.queue_wait``,
+  ``serve.schedule``, ``serve.tick``, ``serve.deliver``; the engine's
+  inside ``serve.tick``).
 
 Determinism contract: the scheduler only ever (a) preserves per-stream
 chunk FIFO order and (b) coalesces *distinct* streams of one chunk length
@@ -76,6 +81,7 @@ import numpy as np
 
 from repro.kernels.lstm_scan.ops import SUBLANES
 
+from . import telemetry
 from .health import ChunkRejectedError, HealthConfig, screen_chunk
 from .latency import ArrivalRateEstimator, LatencyHistogram
 
@@ -263,6 +269,10 @@ class ServerStats:
     scheduler_restarts: int = 0  # supervised scheduler-thread restarts
     checkpoints: int = 0         # periodic engine snapshots written
     batch_fill: Counter = field(default_factory=Counter)
+    # per chunk, enqueue to the return of its push_many: dispatch only for
+    # a chunk that completes no window (the step is not waited for), the
+    # window's decode and read-back for one that does; score delivery is
+    # not in it
     latency: LatencyHistogram = field(default_factory=LatencyHistogram)
 
     def summary(self) -> dict:
@@ -388,61 +398,71 @@ class StreamServer:
         into the engine step; backpressure follows ``config.overflow``
         (``QueueFullError`` semantics unchanged by any health policy).
         """
-        chunk = np.asarray(chunk)
-        if chunk.ndim == 3 and chunk.shape[0] == 1:
-            chunk = chunk[0]
-        # dtype.kind beats two np.issubdtype calls on the per-chunk path
-        # (f=float, i/u=int; bool/complex/str/object all screen out)
-        if chunk.dtype.kind not in "fiu":
-            raise ValueError(
-                f"stream {stream_id!r}: chunk must be real-valued numeric, "
-                f"got dtype {chunk.dtype} (shape {chunk.shape})"
-            )
-        if chunk.ndim != 2 or chunk.shape[0] < 1 or chunk.shape[1] != self._input_dim:
-            raise ValueError(
-                f"stream {stream_id!r}: chunk must be "
-                f"(t, {self._input_dim}) with t >= 1, "
-                f"got {np.asarray(chunk).shape}"
-            )
-        health = self._health
-        if health is not None and health.sanitize != "off":
-            reason = screen_chunk(chunk, health.saturation_limit)
-            if reason is not None:
-                self._quarantine(stream_id, reason)
-                return
-        item = _Pending(stream_id, np.array(chunk), self._clock())
-        with self._cond:
-            while len(self._queue) >= self.config.queue_capacity:
-                if self.config.overflow == "error":
-                    raise QueueFullError(
-                        f"arrival queue full ({self.config.queue_capacity} "
-                        "chunks pending)"
-                    )
-                if self.config.overflow == "drop_oldest":
-                    self._queue.popleft()
-                    self.stats.drops += 1
-                    continue
-                # block: wait for the scheduler to make space
-                if self._thread is None or not self._thread.is_alive():
-                    raise RuntimeError(
-                        "submit would block on a full queue but no scheduler "
-                        "thread is running — start() the server, drain(), or "
-                        "pick a non-blocking overflow policy"
-                    )
-                self._cond.wait()
-            self._queue.append(item)
-            self.stats.submitted += 1
-            est = self._est.get(chunk.shape[0])
-            if est is None:
-                ad = self.config.adaptive
-                est = self._est[chunk.shape[0]] = ArrivalRateEstimator(
-                    alpha=ad.ewma_alpha if ad else 0.25,
-                    idle_reset_factor=(
-                        ad.idle_reset_factor if ad else 50.0
-                    ),
+        with telemetry.span("serve.submit"):
+            chunk = np.asarray(chunk)
+            if chunk.ndim == 3 and chunk.shape[0] == 1:
+                chunk = chunk[0]
+            # dtype.kind beats two np.issubdtype calls on the per-chunk path
+            # (f=float, i/u=int; bool/complex/str/object all screen out)
+            if chunk.dtype.kind not in "fiu":
+                raise ValueError(
+                    f"stream {stream_id!r}: chunk must be real-valued "
+                    f"numeric, got dtype {chunk.dtype} (shape {chunk.shape})"
                 )
-            est.observe(item.t_enqueue)
-            self._cond.notify_all()
+            if (chunk.ndim != 2 or chunk.shape[0] < 1
+                    or chunk.shape[1] != self._input_dim):
+                raise ValueError(
+                    f"stream {stream_id!r}: chunk must be "
+                    f"(t, {self._input_dim}) with t >= 1, "
+                    f"got {np.asarray(chunk).shape}"
+                )
+            health = self._health
+            if health is not None and health.sanitize != "off":
+                reason = screen_chunk(chunk, health.saturation_limit)
+                if reason is not None:
+                    self._quarantine(stream_id, reason)
+                    return
+            item = _Pending(stream_id, np.array(chunk), self._clock())
+            if not self._cond.acquire(blocking=False):
+                # the scheduler holds the queue: the wait is a span of
+                # its own (a free lock costs no span)
+                with telemetry.span("serve.submit_lock"):
+                    self._cond.acquire()
+            try:
+                while len(self._queue) >= self.config.queue_capacity:
+                    if self.config.overflow == "error":
+                        raise QueueFullError(
+                            "arrival queue full "
+                            f"({self.config.queue_capacity} chunks pending)"
+                        )
+                    if self.config.overflow == "drop_oldest":
+                        self._queue.popleft()
+                        self.stats.drops += 1
+                        continue
+                    # block: wait for the scheduler to make space
+                    if self._thread is None or not self._thread.is_alive():
+                        raise RuntimeError(
+                            "submit would block on a full queue but no "
+                            "scheduler thread is running — start() the "
+                            "server, drain(), or pick a non-blocking "
+                            "overflow policy"
+                        )
+                    self._cond.wait()
+                self._queue.append(item)
+                self.stats.submitted += 1
+                est = self._est.get(chunk.shape[0])
+                if est is None:
+                    ad = self.config.adaptive
+                    est = self._est[chunk.shape[0]] = ArrivalRateEstimator(
+                        alpha=ad.ewma_alpha if ad else 0.25,
+                        idle_reset_factor=(
+                            ad.idle_reset_factor if ad else 50.0
+                        ),
+                    )
+                est.observe(item.t_enqueue)
+                self._cond.notify_all()
+            finally:
+                self._cond.release()
 
     def _quarantine(self, stream_id, reason: str) -> None:
         """Apply the configured sanitize policy to one screened-out chunk
@@ -660,7 +680,8 @@ class StreamServer:
         per stream and only chunks of the bucket's length; once a stream
         has been taken *or skipped*, all its later chunks stay queued
         (per-stream FIFO order is what the bit-equality contract rides
-        on).  Stops at the effective width.
+        on).  Stops at the effective width.  Each gathered chunk's time in
+        the queue is recorded as ``serve.queue_wait``.
         """
         if not self._queue:
             return []
@@ -681,6 +702,9 @@ class StreamServer:
                 leftovers.append(item)
             seen.add(sid)
         self._queue = leftovers
+        now = self._clock()
+        for item in batch:
+            telemetry.record("serve.queue_wait", now - item.t_enqueue)
         return batch
 
     def _fire(self, batch: list[_Pending], reason: str) -> None:
@@ -693,7 +717,7 @@ class StreamServer:
         closed while the batch was in flight get their recreated slots
         re-dropped and their scores suppressed, and a raising ``on_score``
         callback is counted + logged instead of killing the scheduler
-        thread.
+        thread.  Its callers make it the span ``serve.tick``.
         """
         ids = [p.stream_id for p in batch]
         if len(batch) == 1:
@@ -827,9 +851,17 @@ class StreamServer:
                 self._last_depth = depth_now
             self._cond.notify_all()  # wake blocked producers
 
+        with telemetry.span("serve.deliver"):
+            self._deliver(batch, res, closed | bad_state)
+
+    def _deliver(self, batch: list[_Pending], res: dict, gone: set) -> None:
+        """Hand each stream's new window scores to ``on_score`` (or the
+        ``pop_scores`` buffer), except those of the streams in ``gone``
+        (closed or reset while in flight) and those a post-reset hold-down
+        withholds."""
         for p in batch:
             sid = p.stream_id
-            if sid in closed or sid in bad_state:
+            if sid in gone:
                 # closed/reset while in flight, or poisoned: these scores
                 # belong to a stream that no longer exists in that lineage
                 continue
@@ -878,18 +910,20 @@ class StreamServer:
             self._heartbeat = now
             if not self._queue:
                 return 0
-            if force:
-                t_bucket, reason = None, "drain"
-            else:
-                t_bucket, reason, _ = self._decide_locked(now)
-                if t_bucket is None:
-                    return 0
-            batch = self._gather_locked(t_bucket)
+            with telemetry.span("serve.schedule"):
+                if force:
+                    t_bucket, reason = None, "drain"
+                else:
+                    t_bucket, reason, _ = self._decide_locked(now)
+                    if t_bucket is None:
+                        return 0
+                batch = self._gather_locked(t_bucket)
             self._inflight = {p.stream_id for p in batch}
             self._closed_inflight = set()
         if not batch:
             return 0
-        self._fire(batch, reason)
+        with telemetry.span("serve.tick"):
+            self._fire(batch, reason)
         return len(batch)
 
     def drain(self) -> int:
@@ -1114,16 +1148,21 @@ class StreamServer:
                     self._heartbeat = self._clock()
                 if self._stopping and not (self._drain_on_stop and self._queue):
                     return
-                t_bucket, reason = None, "drain"
+                reason, batch = "drain", None
                 if not self._stopping:
                     # apply the policy, sleeping only as long as the
                     # tightest remaining per-bucket budget (new submits
-                    # notify and re-decide)
+                    # notify and re-decide); each pass under the lock, a
+                    # decision and the gather when it flushes, is one
+                    # serve.schedule span (the wait is not in it)
                     while not self._stopping and self._queue:
-                        t_bucket, reason, wait_us = self._decide_locked(
-                            self._clock()
-                        )
-                        if t_bucket is not None:
+                        with telemetry.span("serve.schedule"):
+                            t_bucket, reason, wait_us = self._decide_locked(
+                                self._clock()
+                            )
+                            if t_bucket is not None:
+                                batch = self._gather_locked(t_bucket)
+                        if batch is not None:
                             break
                         self._cond.wait(
                             wait_us * 1e-6
@@ -1131,13 +1170,16 @@ class StreamServer:
                             else idle_wait
                         )
                         self._heartbeat = self._clock()
-                    if not self._queue:
-                        continue
-                    if t_bucket is None:  # stop raced the wait: drain
-                        reason = "drain"
-                batch = self._gather_locked(t_bucket)
+                    if batch is None:
+                        if not self._queue:
+                            continue
+                        reason = "drain"  # stop raced the wait
+                if batch is None:
+                    with telemetry.span("serve.schedule"):
+                        batch = self._gather_locked()
                 self._inflight = {p.stream_id for p in batch}
                 self._closed_inflight = set()
             if batch:
-                self._fire(batch, reason)
+                with telemetry.span("serve.tick"):
+                    self._fire(batch, reason)
                 self._maybe_checkpoint()

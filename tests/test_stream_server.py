@@ -29,7 +29,11 @@ except ImportError:  # hermetic container: deterministic fixed-example sweep
 from repro.core.autoencoder import AutoencoderConfig, init_autoencoder
 from repro.kernels.lstm_scan.ops import SUBLANES
 from repro.serve.engine import StreamingAnomalyEngine
-from repro.serve.latency import ArrivalRateEstimator, LatencyHistogram
+from repro.serve.latency import (
+    SUB_BINS,
+    ArrivalRateEstimator,
+    LatencyHistogram,
+)
 from repro.serve.server import (
     AdaptiveConfig,
     QueueFullError,
@@ -103,6 +107,14 @@ class TestLatencyHistogram:
         h = LatencyHistogram()
         h.record(137.0)
         assert h.percentile(50) == 137.0 == h.percentile(99)
+
+    @pytest.mark.parametrize("q", [10, 50, 90, 99])
+    def test_quantiles_lie_within_one_bin_above_the_truth(self, q):
+        h = LatencyHistogram()
+        samples = np.geomspace(10, 10000, 301)
+        h.record_many(samples)
+        truth = np.percentile(samples, q, method="inverted_cdf")
+        assert truth <= h.percentile(q) <= truth * 2 ** (1 / SUB_BINS)
 
     def test_empty(self):
         h = LatencyHistogram()
