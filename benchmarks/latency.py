@@ -1,7 +1,7 @@
 """Shared latency-summary helper for benchmark scripts and the serve CLI.
 
 One histogram implementation serves every latency consumer in the repo —
-``repro.serve.latency.LatencyHistogram`` (fixed geometric us bins,
+``repro.latency.LatencyHistogram`` (fixed geometric us bins,
 O(1) record, p50/p99/max summaries).  The ``StreamServer`` records into
 it natively; this module re-exports it for the benchmark scripts (which
 live outside ``src/``) and adds the one benchmark-side convenience:
@@ -12,7 +12,7 @@ turning a summary into ``(name, us, derived)`` rows for
 
 from __future__ import annotations
 
-from repro.serve.latency import LatencyHistogram
+from repro.latency import LatencyHistogram
 
 __all__ = ["LatencyHistogram", "latency_rows", "record_latencies"]
 

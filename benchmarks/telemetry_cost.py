@@ -1,4 +1,4 @@
-"""Host cost of the serving path's recording (``repro.serve.telemetry``).
+"""Host cost of the serving path's recording (``repro.telemetry``).
 
     PYTHONPATH=src python -m benchmarks.telemetry_cost [--n 100000]
 
@@ -20,7 +20,7 @@ import time
 
 import jax
 
-from repro.serve import telemetry
+from repro import telemetry
 
 
 def unit_costs(n: int) -> dict:

@@ -208,15 +208,15 @@ def decode(
 
     The bridge (RepeatVector) feeds the latent to every decoder timestep,
     so decoding needs only the latent and a length — the streaming engine
-    calls this once per completed window.
+    calls this once per completed window.  The executor takes the latent
+    with the length (``timesteps``), so the fused kernel projects it once
+    per window instead of once per step.
     """
     t = cfg.timesteps if t is None else t
     if executor is None:
         executor = _segment_executor(params, cfg, "dec")
-    h_seq = jnp.broadcast_to(
-        latent[:, None, :], (latent.shape[0], t, latent.shape[1])
-    )
-    out = executor(h_seq, initial_state, return_state=return_state)
+    out = executor(latent, initial_state, return_state=return_state,
+                   timesteps=t)
     h_seq, finals = out if return_state else (out, None)
     # ---- TimeDistributed dense head ----------------------------------------
     # full precision: on the TPU a default fp32 dot is one bf16 pass

@@ -77,8 +77,10 @@ class BackendSpec:
     #: local packed kernels), "fuse_gates" (step kernel's single gate
     #: matmul), "n_chunks" (wavefront hand-off granularity)
     knobs: tuple[str, ...] = ()
-    #: (executor, xs, state) -> (h_seq, finals | None); filled in by
-    #: core.executor when it registers the implementations
+    #: (executor, xs, state) -> (h_seq, finals | None), and with
+    #: ``timesteps=T`` on packed backends (a time-invariant ``(B, D)``
+    #: input, never broadcast over time); filled in by core.executor when
+    #: it registers the implementations
     forward: Any = None
     #: optional native-state hot-path hook: (executor, xs, state) -> state;
     #: backends without one fall back to ``forward`` with portable state
@@ -88,8 +90,8 @@ class BackendSpec:
 #: default ``chunk_len`` for chunked-step backends: long enough to cover
 #: realistic streaming chunk sizes, short enough that the fully-unrolled
 #: T*L step kernel stays a small program (the wavefront kernel wins beyond
-#: this anyway — its one big out-of-kernel mvm_x needs window-scale T to
-#: amortize the HBM round-trip it pays)
+#: this anyway — its grid's per-step pipeline needs window-scale T to
+#: amortize)
 DEFAULT_CHUNK_LEN = 32
 
 

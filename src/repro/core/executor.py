@@ -764,14 +764,27 @@ class StackExecutor:
     # -- full-sequence execution -------------------------------------------
 
     def __call__(self, xs: jax.Array, initial_state=None, *,
-                 return_state: bool = True):
+                 return_state: bool = True, timesteps: int | None = None):
         """Run the segment. xs: (B, T, in_dim) -> (B, T, hidden[-1]).
 
         ``initial_state``/finals are the portable per-layer
         ``[(h, c), ...]`` at real widths — identical across backends, so
         feeding one backend's finals as another's initial state is exact.
+        ``timesteps=T`` takes ``xs`` as ``(B, in_dim)``, the input of every
+        one of T steps (the autoencoder's RepeatVector): the packed backends
+        project it once per row, the others see it broadcast over time.
         """
-        h_seq, finals = self.plan.backend.forward(self, xs, initial_state)
+        spec = self.plan.backend
+        if timesteps is None:
+            h_seq, finals = spec.forward(self, xs, initial_state)
+        elif spec.packs:
+            h_seq, finals = spec.forward(self, xs, initial_state,
+                                         timesteps=timesteps)
+        else:
+            h_seq, finals = spec.forward(
+                self, jnp.broadcast_to(
+                    xs[:, None, :], (xs.shape[0], timesteps, xs.shape[-1])),
+                initial_state)
         if not return_state:
             return h_seq
         if finals is None:
@@ -946,7 +959,7 @@ def _forward_layerwise(ex: StackExecutor, xs, state):
     return h_seq, finals
 
 
-def _forward_fused(ex: StackExecutor, xs, state):
+def _forward_fused(ex: StackExecutor, xs, state, timesteps=None):
     from repro.kernels.lstm_stack.ops import lstm_stack_forward_fused
 
     # bind() already validated the pack against the plan's cfgs; the helper
@@ -954,6 +967,7 @@ def _forward_fused(ex: StackExecutor, xs, state):
     return lstm_stack_forward_fused(
         list(ex.params), xs, list(ex.plan.cfgs), state, packed=ex.packed,
         block_b=ex.plan.block_b, act_bits=ex.plan.act_bits,
+        timesteps=timesteps,
     )
 
 
@@ -970,24 +984,25 @@ def _resolve_n_chunks(plan: StackPlan, t_len: int) -> int:
     return n_stages if t_len % n_stages == 0 else 1
 
 
-def _sharded_call(ex: StackExecutor, xs, h0, c0):
+def _sharded_call(ex: StackExecutor, xs, h0, c0, timesteps=None):
     from repro.core.pipeline import wavefront_shard_map_fused
 
     packed = ex.packed
+    t_len = xs.shape[1] if timesteps is None else timesteps
     return wavefront_shard_map_fused(
-        packed, packed.pad_input(xs), h0, c0,
-        n_chunks=_resolve_n_chunks(ex.plan, xs.shape[1]),
-        mesh=ex.plan.mesh,
+        packed, xs, h0, c0,
+        n_chunks=_resolve_n_chunks(ex.plan, t_len),
+        mesh=ex.plan.mesh, timesteps=timesteps,
     )
 
 
-def _forward_sharded(ex: StackExecutor, xs, state):
+def _forward_sharded(ex: StackExecutor, xs, state, timesteps=None):
     packed = ex.packed
     if state is None:
         h0, c0 = packed.zero_state(xs.shape[0])
     else:
         h0, c0 = packed.pack_state(state)
-    hs, h_f, c_f = _sharded_call(ex, xs, h0, c0)
+    hs, h_f, c_f = _sharded_call(ex, xs, h0, c0, timesteps)
     return hs[..., : packed.hidden[-1]], packed.unpack_state(h_f, c_f)
 
 
@@ -1010,7 +1025,7 @@ def _fused_seq_call(ex: StackExecutor, xs, state):
     from repro.kernels.lstm_stack.ops import lstm_stack_op
 
     return lstm_stack_op(
-        ex.packed.pad_input(xs), ex.packed.stacked, h, c,
+        xs, ex.packed.stacked, h, c,
         acts=ex.packed.acts, weight_dtype=ex.packed.weight_dtype,
         block_b=plan.block_b, act_bits=plan.act_bits,
     )
@@ -1049,14 +1064,16 @@ def _mixed_seq_call(ex: StackExecutor, xs, state):
     return h_seq, tuple(new)
 
 
-def _forward_mixed(ex: StackExecutor, xs, state):
+def _forward_mixed(ex: StackExecutor, xs, state, timesteps=None):
     """Batch path: chain segment ``__call__``s with portable per-layer
-    state slices — identical to hand-chaining the homogeneous segments."""
+    state slices — identical to hand-chaining the homogeneous segments.
+    A repeated input (``timesteps``) is the first segment's alone."""
     h_seq, finals, i = xs, [], 0
     for sub in ex._segment_executors():
         n = sub.plan.n_layers
         s = None if state is None else list(state[i:i + n])
-        h_seq, f = sub(h_seq, s)
+        h_seq, f = sub(h_seq, s, timesteps=timesteps)
+        timesteps = None
         finals.extend(f)
         i += n
     return h_seq, finals

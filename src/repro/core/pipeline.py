@@ -225,12 +225,13 @@ def wavefront_shard_map(
 
 def wavefront_shard_map_fused(
     packed,                 # kernels.lstm_stack.PackedStack for the WHOLE stack
-    xs_p: jax.Array,        # (B, T, W) input, pre-padded to the pack width
+    xs: jax.Array,          # (B, T, D) input at its real width, D <= W
     h0: jax.Array,          # (L, B, W) packed-layout initial hidden
     c0: jax.Array,          # (L, B, W) fp32 initial cell
     n_chunks: int,
     mesh,
     axis: str = "stage",
+    timesteps: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The ``wavefront_shard_map`` schedule with the fused Pallas stack
     kernel as every stage's body (backend ``fused_stack_sharded``).
@@ -249,8 +250,12 @@ def wavefront_shard_map_fused(
     CPU mesh; ``chip_smoke.py --chips 4`` checks the GW nominal scores on
     four v5e chips): chunked sub-stack execution performs the identical per-step
     math in the identical order; only *where* each (layer, chunk) cell
-    evaluates changes.  Returns (hs_last (B, T, W), h_final (L, B, W),
-    c_final fp32 (L, B, W)).
+    evaluates changes.  Stage 0 takes the input at its real width, so
+    its layer 0 takes the form the local kernel takes (``ops.layer0_form``);
+    the later stages take the hand-off, as wide as the pack.  With
+    ``timesteps=T``, ``xs`` is ``(B, D)``, the input of every step, and
+    stage 0 projects it once per chunk (the ``repeat`` form).  Returns
+    (hs_last (B, T, W), h_final (L, B, W), c_final fp32 (L, B, W)).
     """
     from jax.sharding import PartitionSpec as P
 
@@ -259,7 +264,9 @@ def wavefront_shard_map_fused(
     n_stages = mesh.shape[axis]
     n_layers = packed.n_layers
     assert n_layers % n_stages == 0, (n_layers, n_stages)
-    b, t, w = xs_p.shape
+    b, d_in = xs.shape[0], xs.shape[-1]
+    t = xs.shape[1] if timesteps is None else timesteps
+    w = packed.width_p
     assert t % n_chunks == 0, (t, n_chunks)
     ct = t // n_chunks
     perm = [(i, i + 1) for i in range(n_stages - 1)]
@@ -270,19 +277,28 @@ def wavefront_shard_map_fused(
         # xs_local is the full input on every stage, masked by stage id
         # (same scheme as wavefront_shard_map)
         sid = jax.lax.axis_index(axis)
-        chunks = xs_local.reshape(b, n_chunks, ct, w)
-
         def tick(carry, k):
             h, c, inbox = carry
-            x_k = jax.lax.dynamic_index_in_dim(
-                chunks, jnp.clip(k, 0, n_chunks - 1), 1, keepdims=False
-            )
-            feed = jnp.where(sid == 0, x_k, inbox)
-            # the stage body: the whole sub-stack, one Pallas wavefront call
-            hs, h_new, c_new = lstm_stack_op(
-                feed, stacked_local, h, c,
-                acts=acts, weight_dtype=weight_dtype,
-            )
+
+            def stage(feed, steps=None):
+                # the stage body: the whole sub-stack, one Pallas wavefront
+                # call
+                return lstm_stack_op(
+                    feed, stacked_local, h, c, timesteps=steps,
+                    acts=acts, weight_dtype=weight_dtype,
+                )
+
+            if timesteps is None:
+                x_k = jax.lax.dynamic_index_in_dim(
+                    xs_local.reshape(b, n_chunks, ct, d_in),
+                    jnp.clip(k, 0, n_chunks - 1), 1, keepdims=False,
+                )
+                first = lambda x, _: stage(x)  # noqa: E731
+            else:
+                x_k = xs_local
+                first = lambda x, _: stage(x, ct)  # noqa: E731
+            hs, h_new, c_new = jax.lax.cond(
+                sid == 0, first, lambda _, i: stage(i), x_k, inbox)
             # idle stages (fill/drain ticks) must not advance their state
             active = (sid <= k) & (k < sid + n_chunks)
             h = jnp.where(active, h_new, h)
@@ -304,7 +320,7 @@ def wavefront_shard_map_fused(
         in_specs=(P(axis), P(axis), P(axis), P()),
         out_specs=(P(axis), P(axis), P(axis)),
         check_vma=False,
-    )(packed.stacked, h0, c0, xs_p)
+    )(packed.stacked, h0, c0, xs)
     valid = out_ticks[-1, n_stages - 1:]
     return jnp.moveaxis(valid, 0, 1).reshape(b, t, w), h_f, c_f
 

@@ -4,7 +4,10 @@ Public entry points:
 
 * ``lstm_stack_op(xs, stacked, h0, c0)`` — batch-major convenience wrapper
   over an already homogeneous-packed stack (``core/pipeline.pack_lstm_stack``
-  output), handling batch padding/blocking and the layer-0 ``mvm_x`` matmul.
+  output), handling feature and batch padding/blocking and choosing layer
+  0's form from the input's shape (``layer0_form``): a narrow input is
+  projected in-kernel, a repeated one once per row, any other by the
+  layer-0 ``mvm_x`` matmul whose gate tensor the kernel streams.
   Threads an explicit ``(h0, c0) -> (h_f, c_f)`` so callers can carry state
   across calls; with ``alias_state`` (default) the kernel writes the finals
   in place over the initials.
@@ -38,6 +41,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core.quant import (
     WEIGHT_DTYPES,
     ActivationSet,
@@ -54,7 +58,7 @@ from repro.kernels.lstm_scan.ops import (
     choose_blocking,
 )
 
-from .lstm_stack import dot_precision, lstm_stack
+from .lstm_stack import NARROW_MAX_IN, dot_precision, lstm_stack
 
 #: weight storage dtype -> the jnp dtype the packed arrays must hold
 _WEIGHT_JNP = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
@@ -153,19 +157,33 @@ def check_packed_weight_dtype(stacked: dict, weight_dtype: str, compute_dtype) -
     _check_not_wider(weight_dtype, compute_dtype)
 
 
+def layer0_form(in_width: int, pack_width: int, repeat: bool) -> str:
+    """Which of the wavefront kernel's ``LAYER0_FORMS`` a layer-0 input
+    takes, from its shape alone: ``repeat`` for a time-invariant input,
+    ``narrow`` for one at most ``NARROW_MAX_IN`` features wide, ``stream``
+    otherwise.  An input as wide as the pack may already be zero-padded
+    (its real width is then unknown), so it streams."""
+    if repeat:
+        return "repeat"
+    if in_width < pack_width and in_width <= NARROW_MAX_IN:
+        return "narrow"
+    return "stream"
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "block_b", "acts", "interpret", "alias_state", "weight_dtype",
-        "act_bits",
+        "timesteps", "block_b", "acts", "interpret", "alias_state",
+        "weight_dtype", "act_bits",
     ),
 )
 def lstm_stack_op(
-    xs: jax.Array,       # (B, T, W) layer-0 input, pre-padded to the pack width
+    xs: jax.Array,       # (B, T, D) layer-0 input, D <= W; or (B, D) + timesteps
     stacked: dict,       # {"w_x": (L, W, 4W), "w_h": (L, W, 4W), "b": (L, 4W)[, "scales": (L, 2)]}
     h0: jax.Array,       # (L, B, W)
     c0: jax.Array,       # (L, B, W)
     *,
+    timesteps: int | None = None,
     block_b: int | None = None,
     acts: ActivationSet = EXACT,
     interpret: bool | None = None,
@@ -173,49 +191,83 @@ def lstm_stack_op(
     weight_dtype: str = "fp32",
     act_bits: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (hs_last: (B, T, W), h_final: (L, B, W), c_final fp32)."""
+    """Returns (hs_last: (B, T, W), h_final: (L, B, W), c_final fp32).
+
+    ``xs`` is layer 0's input at its real width D (at most the pack width
+    W; this op casts it to the compute dtype and pads it).  With
+    ``timesteps=T``, ``xs`` is ``(B, D)``: the same input at each of T steps
+    (the decoder's RepeatVector), never broadcast to ``(B, T, D)``.  The
+    kernel's layer-0 form follows from the shape (``layer0_form``).  The
+    telemetry counter ``wavefront.layer0_<form>`` counts it once per
+    distinct trace of this op: two programs that call it with the same
+    abstract arguments share one trace and one count.
+    """
     if interpret is None:
         interpret = _on_cpu()
-    batch, t_len, width = xs.shape
-    assert stacked["w_h"].shape[1] == width, (stacked["w_h"].shape, width)
+    batch, width = xs.shape[0], stacked["w_h"].shape[1]
+    assert xs.shape[-1] <= width, (xs.shape, stacked["w_h"].shape)
     check_packed_weight_dtype(stacked, weight_dtype, h0.dtype)
     quantized = weight_dtype == "int8"
+    form = layer0_form(xs.shape[-1], width, repeat=timesteps is not None)
+    telemetry.count(f"wavefront.layer0_{form}")
 
     batch_p, block_b = choose_blocking(batch, block_b, interpret=interpret)
+    compute = h0.dtype
+    xs = xs.astype(compute)
+    pad_b = (0, batch_p - batch)
+    h0_p = jnp.pad(h0, ((0, 0), pad_b, (0, 0)))
+    c0_p = jnp.pad(c0, ((0, 0), pad_b, (0, 0)))
 
-    pad_b = ((0, batch_p - batch), (0, 0), (0, 0))
-    xs_p = jnp.pad(xs, pad_b)
-    h0_p = jnp.pad(h0, ((0, 0), (0, batch_p - batch), (0, 0)))
-    c0_p = jnp.pad(c0, ((0, 0), (0, batch_p - batch), (0, 0)))
-
-    # sub-layer 1 for layer 0 (paper mvm_x): ONE big MXU matmul + bias,
-    # then time-major for the sequential wavefront axis.  Same dequant order
-    # as the kernel's inner layers: cast codes to the compute dtype, matmul,
-    # scale the fp32 result.
-    w0 = stacked["w_x"][0]
-    if w0.dtype != xs_p.dtype:
-        w0 = w0.astype(xs_p.dtype)
-    # the accumulator is rounded to the compute dtype before widening; the
-    # explicit round trip keeps XLA from folding the widen into the dot,
-    # which would skip the bf16 rounding the step kernel performs
-    xw0 = jnp.dot(
-        xs_p, w0, preferred_element_type=jnp.float32,
-        precision=dot_precision(xs_p.dtype),
-    ).astype(xs_p.dtype).astype(jnp.float32)
-    if quantized:
-        scales = normalize_scales(stacked["scales"], stacked["w_h"].shape[0])
-        xw0 = apply_gate_scales(xw0, scales[0, 0])
-    xw0 = xw0 + stacked["b"][0]
-    xw0 = jnp.swapaxes(xw0, 0, 1)  # (T, Bp, 4W)
+    if form == "narrow":
+        x0 = jnp.pad(xs, (pad_b, (0, 0), (0, 0)))
+    else:
+        # sub-layer 1 for layer 0 (paper mvm_x): ONE big MXU matmul + bias
+        # ("stream": then time-major for the sequential wavefront axis;
+        # "repeat": one row per window, the kernel reuses it every step).
+        # Same dequant order as the kernel's inner layers: cast codes to
+        # the compute dtype, matmul, scale the fp32 result.
+        pad_f = (0, width - xs.shape[-1])
+        xs_p = jnp.pad(xs, (pad_b,) + ((0, 0),) * (xs.ndim - 2) + (pad_f,))
+        if form == "repeat" and interpret:
+            # CPU only: XLA:CPU picks a dot's kernel by its shape, and rows
+            # of a dot with few rows round differently from the same rows
+            # of a large one, so a lone stream's window would not decode as
+            # it does in a batch.  Here the rows are projected broadcast
+            # over time, as the stream form does, and the first step kept.
+            # The chip projects the (B, D) rows alone (its matmul rounds
+            # each row alike at any row count); only chip_smoke.py, which
+            # holds streamed scores to the batch's bit for bit on the chip,
+            # checks that projection's rounding
+            xs_p = jnp.broadcast_to(xs_p[:, None], (batch_p, timesteps, width))
+        w0 = stacked["w_x"][0]
+        if w0.dtype != compute:
+            w0 = w0.astype(compute)
+        # the accumulator is rounded to the compute dtype before widening;
+        # the explicit round trip keeps XLA from folding the widen into the
+        # dot, which would skip the bf16 rounding the step kernel performs
+        x0 = jnp.dot(
+            xs_p, w0, preferred_element_type=jnp.float32,
+            precision=dot_precision(compute),
+        ).astype(compute).astype(jnp.float32)
+        if quantized:
+            scales = normalize_scales(stacked["scales"], stacked["w_h"].shape[0])
+            x0 = apply_gate_scales(x0, scales[0, 0])
+        x0 = x0 + stacked["b"][0]
+        if form == "stream":
+            x0 = jnp.swapaxes(x0, 0, 1)  # (T, Bp, 4W)
+        elif interpret:
+            x0 = x0[:, 0]
 
     acts_k = kernel_safe(acts)
     hs, h_f, c_f = lstm_stack(
-        xw0,
+        x0,
         stacked["w_x"],
         stacked["w_h"],
         stacked["b"].astype(jnp.float32),
         h0_p,
         c0_p.astype(jnp.float32),
+        form=form,
+        t_len=timesteps,
         scales=stacked["scales"] if quantized else None,
         block_b=block_b,
         sigma=acts_k.sigma,
@@ -482,13 +534,14 @@ def check_packed_matches_cfgs(packed: PackedStack, cfgs: Sequence) -> None:
 
 def lstm_stack_forward_fused(
     params_list: Sequence[dict[str, Any]],
-    xs: jax.Array,  # (B, T, in_dim of layer 0)
+    xs: jax.Array,  # (B, T, in_dim of layer 0), or (B, in_dim) + timesteps
     cfgs: Sequence,  # list[LstmConfig], one per layer
     initial_state: Sequence[tuple[jax.Array, jax.Array]] | None = None,
     *,
     packed: PackedStack | None = None,
     block_b: int | None = None,
     act_bits: int | None = None,
+    timesteps: int | None = None,
 ) -> tuple[jax.Array, list[tuple[jax.Array, jax.Array]]]:
     """Backend for core.lstm.lstm_stack_forward(impl="fused_stack").
 
@@ -500,6 +553,8 @@ def lstm_stack_forward_fused(
     pack entirely — the serve path does this once at engine init.
     ``block_b`` overrides the kernel's hand-set batch tile (a tuned plan's
     knob rides through here; None keeps ``choose_blocking``'s default).
+    ``timesteps=T`` takes ``xs`` as one ``(B, in_dim)`` input repeated at
+    every step (``lstm_stack_op``'s ``repeat`` form).
     """
     if packed is None:
         packed = pack_stack_cached(params_list, cfgs)
@@ -513,7 +568,7 @@ def lstm_stack_forward_fused(
         h0, c0 = packed.pack_state(initial_state)
 
     hs, h_f, c_f = lstm_stack_op(
-        packed.pad_input(xs), packed.stacked, h0, c0, acts=packed.acts,
+        xs, packed.stacked, h0, c0, timesteps=timesteps, acts=packed.acts,
         weight_dtype=packed.weight_dtype, block_b=block_b,
         act_bits=act_bits,
     )

@@ -3,13 +3,13 @@
 The serving-time critical path the paper optimizes (Sec. III, Fig. 7) is the
 *initiation interval* of a streamed sample: a new LIGO strain sample arrives
 every sampling period and must advance the resident LSTM state with minimal
-latency.  The wavefront kernel (``lstm_stack.py``) is built for throughput —
-its grid walks ``T + L - 1`` sequential steps and its layer-0 input
-projection is a separate XLA matmul whose ``(T, B, 4W)`` result round-trips
-through HBM.  Both choices are right at window scale and wrong at chunk
-scale: at ``T = 1`` the pre-kernel matmul is a tiny kernel launch plus an
-HBM round-trip that costs more than the math, and the wavefront grid
-degenerates to ``L`` masked steps.
+latency.  The wavefront kernel (``lstm_stack.py``) is built for throughput:
+its grid walks ``T + L - 1`` sequential steps, one per-step pipeline stage
+each, and it forms layer 0's gates in one of three forms (a narrow input
+in-kernel on the VPU, a repeated one once per row, any other as a
+``(T, B, 4W)`` gate tensor streamed from HBM).  That is right at window
+scale and wrong at chunk scale: at ``T = 1`` the wavefront grid
+degenerates to ``L`` masked steps, each paying the grid's per-step cost.
 
 This kernel is the step-scale specialization, for ``T in {1..chunk_len}``:
 
@@ -227,8 +227,9 @@ def lstm_stack_step(
     block.  Shapes pre-padded by the op wrapper; returns
     (hs_last: (B, T, W), h_final: (L, B, W), c_final fp32: (L, B, W)).
 
-    Unlike ``lstm_stack`` the input is the *raw* chunk — layer 0's gate
-    projection happens in-kernel, so no ``(T, B, 4W)`` tensor ever exists.
+    The input is the *raw* chunk, pre-padded to the pack width — layer 0's
+    gate projection happens in-kernel, so no ``(T, B, 4W)`` tensor ever
+    exists.
     Inside the kernel the chunk is time-major, so timestep ``t`` is a
     leading-axis ref load; at the serving-critical T=1 the swap in and out
     is a free reshape.  ``alias_state`` maps h0/c0 onto the finals exactly
@@ -338,9 +339,10 @@ def lstm_stack_step_op(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Step-path twin of ``lstm_stack_op`` for short chunks.
 
-    Differences on the hot path: no out-of-kernel mvm_x (layer 0 projects
-    in-kernel from the raw chunk), no gate-sized time-major transposes (the
-    raw chunk's is a reshape at T=1), and one grid step per batch block.
+    Differences on the hot path: layer 0 projects in-kernel from the raw
+    chunk at any input width, with the MXU (the wavefront kernel does so
+    only for narrow inputs, on the VPU), no time-major transposes (the raw
+    chunk's is a reshape at T=1), and one grid step per batch block.
     Returns the same
     (hs: (B, T, W), h_final: (L, B, W), c_final fp32) triple.
 

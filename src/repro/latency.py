@@ -14,7 +14,7 @@ stages stay apart, where 9% bins put them on one edge).
 
 One implementation serves every consumer: the ``StreamServer`` records
 per-chunk latency from enqueue to the end of its ``push_many``, the
-serving spans (``serve/telemetry.py``) their durations, the
+serving spans (``repro/telemetry.py``) their durations, the
 ``launch/serve`` CLI summarizes its per-window latencies through it
 (replacing the old ad-hoc ``np.percentile`` lines), and
 ``benchmarks/server_bench`` /

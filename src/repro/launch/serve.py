@@ -35,12 +35,13 @@ already pending or the batch cannot fill within the cap.  Per-chunk
 latency from enqueue to the end of its ``push_many`` lands in a fixed-bin
 histogram; the run prints p50/p99/max plus the scheduler's tick, flush,
 batch-fill, and drop counters.  Both anomaly loops end with the host time
-by stage: p50/p99 and count of each span of ``serve/telemetry.py`` (in
+by stage: p50/p99 and count of each span of ``repro/telemetry.py`` (in
 server mode over the run: submit, queue wait, scheduling, tick, the
 engine's step, zero-state creation, window finish and its sync,
 delivery; without it, the calibration's ``score`` call and, with
-``--streams``, ``push_many``), with the zero states created and the
-programs built.
+``--streams``, ``push_many``), with the zero states created, the
+programs built and the layer-0 forms of the wavefront kernel, counted
+once per distinct kernel trace.
 ``--sanitize {off,reject,hold,reset}`` screens every submitted chunk for
 NaN/Inf (and ``--saturation-limit``) before it can enter a batch, with
 the chosen quarantine policy; ``--checkpoint PATH`` snapshots the engine
@@ -63,12 +64,13 @@ import time
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.configs import get_arch
+from repro.kernels.lstm_stack.lstm_stack import LAYER0_FORMS
+from repro.latency import LatencyHistogram
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model
-from repro.serve import telemetry
 from repro.serve.engine import LmEngine
-from repro.serve.latency import LatencyHistogram
 
 
 def main():
@@ -365,7 +367,13 @@ def serve_server(args, params, cfg, ds):
     for wid in warm_ids:
         engine.drop_stream(wid)
 
-    telemetry.reset()  # the stage line covers the run, not the warm-up
+    # the stage line covers the run, not the warm-up; the layer-0 forms are
+    # facts of the kernel traces made, so the warm-up's carry over
+    forms = {k: n for k, n in telemetry.snapshot()["counters"].items()
+             if k.startswith("wavefront.layer0_")}
+    telemetry.reset()
+    for name, n in forms.items():
+        telemetry.count(name, n)
     t0 = time.perf_counter()
     with server:
         live = [i for i, q in enumerate(queues) if q]
@@ -407,16 +415,23 @@ def serve_server(args, params, cfg, ds):
 
 def print_stages() -> None:
     """One line: the host time of each serving stage (p50/p99 us and
-    count, from ``serve/telemetry.py``) and the recorder's counters."""
+    count, from ``repro/telemetry.py``) and the recorder's counters, among
+    them the wavefront kernel's layer-0 forms, one count per distinct
+    kernel trace (``wavefront.layer0_<form>``)."""
     snap = telemetry.snapshot()
     stages = ", ".join(
         f"{name} {st['p50_us']:.0f}/{st['p99_us']:.0f}us x{st['count']}"
         for name, st in snap["spans"].items()
     )
     counters = snap["counters"]
+    forms = ", ".join(
+        f"{form} {counters.get(f'wavefront.layer0_{form}', 0)}"
+        for form in LAYER0_FORMS
+    )
     print(f"host time by stage (p50/p99, count): {stages}; "
           f"{counters.get('engine.states_created', 0)} zero states created, "
-          f"{counters.get('engine.programs_built', 0)} programs built")
+          f"{counters.get('engine.programs_built', 0)} programs built, "
+          f"wavefront layer-0 forms traced: {forms}")
 
 
 def print_plan(args, params, cfg) -> None:

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.configs.base import ArchConfig
 from repro.core.autoencoder import (
     AutoencoderConfig,
@@ -54,7 +55,6 @@ from repro.core.backends import (  # noqa: F401  (re-exports)
 )
 from repro.kernels.lstm_scan.ops import SUBLANES
 from repro.models.api import get_model
-from repro.serve import telemetry
 from repro.serve.health import (
     SNAPSHOT_VERSION,
     check_fingerprint,

@@ -24,7 +24,7 @@ continuous-batching loop LLM serving uses:
   a batch is full;
 * **adaptive policy** (``ServerConfig.adaptive``) — instead of a fixed
   ``deadline_us``, the scheduler estimates each bucket's arrival rate
-  with an EWMA over inter-arrival gaps (``serve/latency.py``) and picks
+  with an EWMA over inter-arrival gaps (``repro/latency.py``) and picks
   the deadline that fills the batch with high probability under that
   rate, capped by ``max_deadline_us`` — and when even the cap cannot
   fill it, flushes at once rather than waiting out a budget that buys
@@ -47,7 +47,7 @@ continuous-batching loop LLM serving uses:
   the window's decode and score read-back, for any other chunk only the
   step's dispatch.  Plus tick counts, the batch-fill distribution,
   deadline-vs-full flush counts, and drops.  Each stage of a tick is a
-  span of ``serve/telemetry.py`` (``serve.submit``, ``serve.queue_wait``,
+  span of ``repro/telemetry.py`` (``serve.submit``, ``serve.queue_wait``,
   ``serve.schedule``, ``serve.tick``, ``serve.deliver``; the engine's
   inside ``serve.tick``).
 
@@ -83,7 +83,7 @@ from repro.kernels.lstm_scan.ops import SUBLANES
 
 from . import telemetry
 from .health import ChunkRejectedError, HealthConfig, screen_chunk
-from .latency import ArrivalRateEstimator, LatencyHistogram
+from repro.latency import ArrivalRateEstimator, LatencyHistogram
 
 __all__ = [
     "AdaptiveConfig",

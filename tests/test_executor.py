@@ -301,6 +301,10 @@ for wd in (None, "int8"):
     h_l2, _ = local(xs, f_l)
     h_s2, _ = sharded(xs, f_l)
     np.testing.assert_array_equal(np.asarray(h_s2), np.asarray(h_l2))
+    # a repeated input (RepeatVector): stage 0 projects it as local does
+    np.testing.assert_array_equal(
+        np.asarray(sharded(xs[:, 0], timesteps=16, return_state=False)),
+        np.asarray(local(xs[:, 0], timesteps=16, return_state=False)))
 
 # every legal chunking is equivalent
 ref = np.asarray(plan_stack(cfgs, impl="fused_stack").bind(params)(

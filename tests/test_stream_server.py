@@ -29,7 +29,7 @@ except ImportError:  # hermetic container: deterministic fixed-example sweep
 from repro.core.autoencoder import AutoencoderConfig, init_autoencoder
 from repro.kernels.lstm_scan.ops import SUBLANES
 from repro.serve.engine import StreamingAnomalyEngine
-from repro.serve.latency import (
+from repro.latency import (
     SUB_BINS,
     ArrivalRateEstimator,
     LatencyHistogram,

@@ -1,4 +1,4 @@
-"""The serving path's span recorder (``serve/telemetry.py``): durations,
+"""The serving path's span recorder (``repro/telemetry.py``): durations,
 self time, counters, snapshot/reset, per-thread span stacks, and the spans
 in a profiler trace, nested on the device's clock."""
 
@@ -9,10 +9,10 @@ import jax
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.core.autoencoder import AutoencoderConfig, init_autoencoder
-from repro.serve import telemetry
 from repro.serve.engine import StreamingAnomalyEngine
-from repro.serve.latency import SUB_BINS, LatencyHistogram
+from repro.latency import SUB_BINS, LatencyHistogram
 from repro.serve.server import ServerConfig, StreamServer
 
 
