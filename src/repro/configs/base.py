@@ -19,7 +19,7 @@ import jax.numpy as jnp
 @dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                    # dense | moe | ssm | hybrid | encdec
+    family: str                    # dense | moe | ssm | hybrid | seq_hybrid | encdec
     n_layers: int
     d_model: int
     n_heads: int
@@ -45,6 +45,14 @@ class ArchConfig:
     attn_free: bool = False        # mamba2: no attention at all
     hybrid: bool = False           # hymba: parallel attn + SSM heads per layer
     sliding_window: int | None = None  # bounded attention window (hybrid)
+    ssm_chunk: int = 64            # SSD chunk length of the chunked scan
+    conv_bias: bool = False        # bias on the SSM branch's depthwise conv
+    # --- sequential hybrid (granite-4.0-h: one mixer per layer, NoPE) --------
+    layer_types: tuple[str, ...] = ()  # per layer: "mamba" | "attention"
+    attention_multiplier: float | None = None  # score scale (None: 1/sqrt(hd))
+    embedding_multiplier: float = 1.0  # the embeddings are multiplied by it
+    residual_multiplier: float = 1.0   # on both residual branches
+    logits_scaling: float = 1.0        # the logits are divided by it
     # --- encoder-decoder -----------------------------------------------------
     encdec: bool = False
     n_enc_layers: int = 0          # encoder depth (decoder depth = n_layers)
@@ -81,9 +89,9 @@ class ArchConfig:
 
     @property
     def ssm_heads(self) -> int:
-        if not (self.attn_free or self.hybrid):
+        if not (self.attn_free or self.hybrid or self.layer_types):
             return 0
-        d_inner = self.ssm_expand * self.d_model if self.attn_free else self.d_model
+        d_inner = self.d_model if self.hybrid else self.ssm_expand * self.d_model
         return d_inner // self.ssm_head_dim
 
     def n_params(self) -> float:
@@ -92,6 +100,15 @@ class ArchConfig:
         hd, h, kv = self.hd, self.n_heads, self.n_kv_heads
         attn = d * hd * (h + 2 * kv) + h * hd * d
         dense_mlp = 3 * d * ff
+        if self.layer_types:  # one mixer per layer, each followed by the MLP
+            din = self.ssm_expand * d
+            gn = self.ssm_groups * self.ssm_state
+            mamba = (d * (2 * din + 2 * gn + self.ssm_heads) + din * d
+                     + (din + 2 * gn) * self.conv_kernel)
+            n_attn = self.layer_types.count("attention")
+            total = (n_attn * attn + (l - n_attn) * mamba + l * dense_mlp
+                     + self.vocab * d * (1 if self.tie_embeddings else 2))
+            return float(total)
         per_layer = 0.0
         if not self.attn_free:
             per_layer += attn
@@ -124,7 +141,7 @@ class ArchConfig:
 
     def reduced(self) -> "ArchConfig":
         """Small same-family variant: CPU smoke tests run a real fwd/train step."""
-        return replace(
+        small = replace(
             self,
             n_layers=2,
             d_model=64,
@@ -150,6 +167,9 @@ class ArchConfig:
             frontend_tokens=8 if self.frontend else 0,
             dtype=jnp.float32,
         )
+        if self.layer_types:  # both kinds of mixer in the two layers
+            small = replace(small, layer_types=("mamba", "attention"))
+        return small
 
 
 @dataclass(frozen=True)

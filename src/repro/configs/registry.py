@@ -1,4 +1,5 @@
-"""--arch registry: the 10 assigned architectures + the paper's GW models."""
+"""--arch registry: the 10 assigned architectures, granite-4.0-h-micro (served
+by the slot engine) + the paper's GW models."""
 
 from __future__ import annotations
 
@@ -8,6 +9,7 @@ from repro.configs.llava_next_34b import CONFIG as _llava
 from repro.configs.yi_9b import CONFIG as _yi
 from repro.configs.qwen1_5_4b import CONFIG as _qwen_dense
 from repro.configs.granite_3_2b import CONFIG as _granite
+from repro.configs.granite_4_0_h_micro import CONFIG as _granite_h
 from repro.configs.smollm_360m import CONFIG as _smollm
 from repro.configs.mamba2_130m import CONFIG as _mamba2
 from repro.configs.hymba_1_5b import CONFIG as _hymba
@@ -19,7 +21,7 @@ ARCHS: dict[str, ArchConfig] = {
     c.name: c
     for c in (
         _llava, _yi, _qwen_dense, _granite, _smollm,
-        _mamba2, _hymba, _dbrx, _qwen_moe, _seamless,
+        _mamba2, _hymba, _dbrx, _qwen_moe, _seamless, _granite_h,
     )
 }
 

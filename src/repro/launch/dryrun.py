@@ -1,13 +1,20 @@
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes, prove memory fit, and emit roofline inputs.
 
-MUST be imported/executed before any other jax usage: the first two lines
-pin 512 placeholder host devices (jax locks the device count on first init).
+``main`` pins 512 placeholder host devices before JAX first looks for
+devices (jax locks the device count on first init); importing the module
+pins nothing, so a test may import ``compile_slot_programs``.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch yi-9b --shape train_4k
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multipod-only-train4k]
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh pod --out runs/
+    PYTHONPATH=src python -m repro.launch.dryrun --arch granite-4.0-h-micro
+
+An arch served by the slot engine (``serve/slots.py``), given without
+``--shape``, has its slot programs (every prefill rung and the decode
+step, 48 slots of 2560 tokens) compiled for one described v5e chip at its
+published widths, with the memory each program needs.
 
 Per cell it reports/serializes:
     bytes per device (arguments / outputs / temps from memory_analysis),
@@ -16,15 +23,8 @@ Per cell it reports/serializes:
     and writes the per-cell JSON consumed by benchmarks/roofline_table.py.
 """
 
-import os
-
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", "")
-)
-
-# ruff: noqa: E402
 import argparse
+import os
 import gc
 import json
 import time
@@ -251,7 +251,53 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
     return rec
 
 
+def compile_slot_programs(arch_name: str, device, *, slots: int = 48,
+                          capacity: int = 2560, rungs=None) -> dict:
+    """Compile the slot engine's programs for ``arch_name`` at its published
+    widths for ``device`` (a described chip; nothing is allocated):
+    ``{program: memory_analysis}`` for each ladder rung (``rungs``, default
+    all) and the decode step."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.serve import slots as slot_engine
+
+    cfg = get_arch(arch_name)
+    sharding = SingleDeviceSharding(device)
+    # row-major, as the engine's arrays are: left to choose, the compiler
+    # picks layouts that no array on the chip has
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=Format(Layout(tuple(range(a.ndim))), sharding)),
+        tree)
+    params = place(abstract_params(cfg))
+    state = place(jax.eval_shape(
+        lambda: slot_engine.init_state(cfg, slots, capacity)))
+    out = {}
+    for key in (*(rungs or slot_engine.PREFILL_LADDER), "step"):
+        compiled = slot_engine.lower(cfg, key, params, state, sharding).compile()
+        out[key] = compiled.memory_analysis()
+    return out
+
+
+def _slot_dryrun(arch_name: str) -> None:
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    for key, mem in compile_slot_programs(arch_name, topo.devices[0]).items():
+        print(f"[ok] {arch_name} slot program {key}: "
+              f"args={mem.argument_size_in_bytes / 2**30:.2f}GiB "
+              f"temp={mem.temp_size_in_bytes / 2**30:.2f}GiB "
+              f"alias={mem.alias_size_in_bytes / 2**30:.2f}GiB", flush=True)
+
+
 def main():
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", "")
+    )
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)
@@ -269,6 +315,8 @@ def main():
         for arch in sorted(ARCHS):
             for shape in SHAPES:
                 cells.append((arch, shape))
+    elif args.arch and not args.shape and get_arch(args.arch).family == "seq_hybrid":
+        return _slot_dryrun(args.arch)
     else:
         assert args.arch and args.shape, "--arch/--shape or --all"
         cells.append((args.arch, args.shape))

@@ -5,6 +5,17 @@ LM mode (batched prefill + decode):
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m --reduced \
         --prompt-len 16 --new-tokens 16
 
+An arch whose sequences carry recurrent state beside their KV cache
+(``family="seq_hybrid"``: granite-4.0-h-micro) is served by the slot
+engine (``serve/slots.py``): ``--batch`` slots, each prefilled on
+admission and decoded at its own length; ``2 * --batch`` requests of
+``--prompt-len`` prompt and ``--new-tokens`` output tokens pass through
+them, and the run ends with the host time by stage:
+
+    PYTHONPATH=src python -m repro.launch.serve --mode lm \
+        --arch granite-4.0-h-micro --batch 48 --prompt-len 512 \
+        --new-tokens 192
+
 Anomaly mode (the paper's use case — persistent-state B=1 streaming on the
 fused stack, weights pre-packed at engine init, state donated per chunk;
 short chunks ride the ``fused_step`` low-latency step kernel):
@@ -185,6 +196,8 @@ def main():
         cfg = cfg.reduced()
     api = get_model(cfg)
     params = api.init_params(jax.random.PRNGKey(0), cfg)
+    if cfg.family == "seq_hybrid":
+        return serve_slots(args, params, cfg)
 
     engine = LmEngine(params, cfg, max_len=args.prompt_len + args.new_tokens)
     rng = np.random.default_rng(0)
@@ -197,6 +210,32 @@ def main():
     print(f"{args.arch}: generated {out.shape} in {dt:.2f}s "
           f"({tok_s:.1f} tok/s on this host)")
     print("sample:", out[0][:12].tolist())
+
+
+def serve_slots(args, params, cfg):
+    """Requests through the slot engine, every slot kept busy."""
+    from repro.serve.slots import Request, SlotEngine, generate
+
+    engine = SlotEngine(params, cfg, slots=args.batch,
+                        capacity=args.prompt_len + args.new_tokens)
+    t0 = time.perf_counter()
+    engine.compile()
+    t_compile = time.perf_counter() - t0
+    rng = np.random.default_rng(0)
+    requests = [
+        Request(rng.integers(0, cfg.vocab, args.prompt_len).astype(np.int32),
+                args.new_tokens)
+        for _ in range(2 * args.batch)]
+    t0 = time.perf_counter()
+    out = [tokens for tokens, _, _ in generate(engine, requests)]
+    dt = time.perf_counter() - t0
+    n_out = sum(len(o) for o in out)
+    print(f"{args.arch}: {len(out)} requests through {args.batch} slots, "
+          f"{n_out} tokens in {dt:.2f}s ({n_out / dt:.1f} tok/s on "
+          f"{jax.devices()[0].device_kind}); programs built in "
+          f"{t_compile:.1f}s")
+    print("sample:", out[0][:12].tolist())
+    print_stages(lm=True)
 
 
 def serve_anomaly(args):
@@ -413,17 +452,24 @@ def serve_server(args, params, cfg, ds):
               + (f" -> {args.checkpoint}" if args.checkpoint else ""))
 
 
-def print_stages() -> None:
+def print_stages(lm: bool = False) -> None:
     """One line: the host time of each serving stage (p50/p99 us and
-    count, from ``repro/telemetry.py``) and the recorder's counters, among
-    them the wavefront kernel's layer-0 forms, one count per distinct
-    kernel trace (``wavefront.layer0_<form>``)."""
+    count, from ``repro/telemetry.py``) and the recorder's counters: for
+    the slot engine (``lm``) its ``lm.*`` counters, else the stream
+    engine's, among them the wavefront kernel's layer-0 forms, one count
+    per distinct kernel trace (``wavefront.layer0_<form>``)."""
     snap = telemetry.snapshot()
     stages = ", ".join(
         f"{name} {st['p50_us']:.0f}/{st['p99_us']:.0f}us x{st['count']}"
         for name, st in snap["spans"].items()
     )
     counters = snap["counters"]
+    if lm:
+        print(f"host time by stage (p50/p99, count): {stages}; "
+              + ", ".join(f"{name} {counters.get(name, 0)}" for name in (
+                  "lm.prefill_tokens", "lm.tokens_out", "lm.slot_steps",
+                  "lm.programs_built")))
+        return
     forms = ", ".join(
         f"{form} {counters.get(f'wavefront.layer0_{form}', 0)}"
         for form in LAYER0_FORMS

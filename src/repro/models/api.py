@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, InputShape
-from repro.models import encdec, hybrid, moe, ssm, transformer
+from repro.models import encdec, hybrid, moe, seq_hybrid, ssm, transformer
 from repro.models.encdec import ENC_LEN_DECODE
 
 
@@ -37,6 +37,7 @@ _FAMILIES = {
     "moe": moe,
     "ssm": ssm,
     "hybrid": hybrid,
+    "seq_hybrid": seq_hybrid,
     "encdec": encdec,
 }
 

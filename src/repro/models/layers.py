@@ -154,6 +154,7 @@ def sdpa(
     q_offset: jax.Array | int = 0,   # absolute position of q[0] (decode)
     kv_len: jax.Array | None = None,  # valid cache length (masks padded tail)
     window: int | None = None,        # sliding-window width (tokens back)
+    scale: float | None = None,       # score scale (None: 1/sqrt(D))
 ) -> jax.Array:
     """Masked GQA scaled-dot-product attention (pure jnp; XLA fuses well).
 
@@ -169,7 +170,8 @@ def sdpa(
     qf = q.reshape(b, sq, hkv, g, d)
     scores = jnp.einsum(
         "bqhgd,bkhd->bhgqk", qf, k, preferred_element_type=jnp.float32
-    ) / d**0.5
+    )
+    scores = scores / d**0.5 if scale is None else scores * scale
 
     q_pos = jnp.arange(sq) + q_offset          # (Sq,)
     k_pos = jnp.arange(sk)                     # (Sk,)

@@ -108,8 +108,10 @@ def ssd_chunked(
 # depthwise causal conv (width K, shift-add form)
 # ---------------------------------------------------------------------------
 
-def causal_conv(x: jax.Array, w: jax.Array, state: jax.Array | None = None):
-    """x (B,T,Ch), w (Ch,K) -> (B,T,Ch). state (B,K-1,Ch) prepends history."""
+def causal_conv(x: jax.Array, w: jax.Array, state: jax.Array | None = None,
+                bias: jax.Array | None = None):
+    """x (B,T,Ch), w (Ch,K) -> (B,T,Ch). state (B,K-1,Ch) prepends history;
+    ``bias`` (Ch,) is added before the result is cast to x's dtype."""
     k = w.shape[1]
     if state is None:
         x_pad = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
@@ -119,6 +121,8 @@ def causal_conv(x: jax.Array, w: jax.Array, state: jax.Array | None = None):
         x_pad[:, i : i + x.shape[1], :] * w[None, None, :, k - 1 - i]
         for i in range(k)
     )
+    if bias is not None:
+        out = out + bias
     return out.astype(x.dtype)
 
 
@@ -138,7 +142,7 @@ def init_ssm_block(key, cfg: ArchConfig, hybrid_branch: bool = False) -> dict:
     d_inner, heads, gn, conv_ch = _dims(cfg, hybrid_branch)
     ks = jax.random.split(key, 4)
     proj_out = 2 * d_inner + 2 * gn + heads  # z | x | B | C | dt
-    return {
+    p = {
         "in_proj": L.dense_init(ks[0], cfg.d_model, proj_out, cfg.dtype),
         "conv_w": (jax.random.normal(ks[1], (conv_ch, cfg.conv_kernel), jnp.float32) * 0.2),
         "a_log": jnp.zeros((heads,), jnp.float32),        # A = -exp(a_log) = -1
@@ -147,6 +151,9 @@ def init_ssm_block(key, cfg: ArchConfig, hybrid_branch: bool = False) -> dict:
         "norm": jnp.ones((d_inner,), jnp.float32),
         "out_proj": L.dense_init(ks[2], d_inner, cfg.d_model, cfg.dtype),
     }
+    if cfg.conv_bias:
+        p["conv_b"] = jax.random.normal(ks[3], (conv_ch,), jnp.float32) * 0.1
+    return p
 
 
 def _split_proj(p, u, cfg: ArchConfig, hybrid_branch: bool):
@@ -158,20 +165,28 @@ def _split_proj(p, u, cfg: ArchConfig, hybrid_branch: bool):
 def ssm_block(
     p: dict, x_in: jax.Array, cfg: ArchConfig,
     hybrid_branch: bool = False, chunk: int = 64,
-    state: dict | None = None,
+    state: dict | None = None, lengths: jax.Array | None = None,
 ) -> tuple[jax.Array, dict]:
-    """Full-sequence SSM block. Returns (out (B,T,d), final decode state)."""
+    """Full-sequence SSM block. Returns (out (B,T,d), final decode state).
+
+    ``lengths`` (B,): each row holds that many real steps, then padding.
+    A padded step has dt = 0, which leaves the SSD state exactly as it was,
+    and the conv window is read at the row's length, so the state returned
+    is the unpadded sequence's."""
     b, t, _ = x_in.shape
     u = x_in @ p["in_proj"]
     z, xbc, dt_raw, (d_inner, heads, gn) = _split_proj(p, u, cfg, hybrid_branch)
     conv_state_in = None if state is None else state["conv"]
-    xbc = jax.nn.silu(causal_conv(xbc, p["conv_w"], conv_state_in))
+    xbc = jax.nn.silu(causal_conv(xbc, p["conv_w"], conv_state_in, p.get("conv_b")))
     xs, bm, cm = jnp.split(xbc, [d_inner, d_inner + gn], axis=-1)
     n, g = cfg.ssm_state, cfg.ssm_groups
     xh = xs.reshape(b, t, heads, cfg.ssm_head_dim)
     bm = bm.reshape(b, t, g, n)
     cm = cm.reshape(b, t, g, n)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
+    if lengths is not None:
+        real = jnp.arange(t)[None, :] < lengths[:, None]
+        dt = jnp.where(real[..., None], dt, 0.0)
     a = -jnp.exp(p["a_log"])
     s0 = None if state is None else state["ssd"]
     y, s_f = ssd_chunked(xh, dt, a, bm, cm, s0=s0, chunk=chunk)
@@ -185,7 +200,13 @@ def ssm_block(
         hist = jnp.concatenate([state["conv"].astype(xbc_raw.dtype), xbc_raw], axis=1)
     else:
         hist = jnp.pad(xbc_raw, ((0, 0), (k - 1, 0), (0, 0)))
-    new_state = {"conv": hist[:, -(k - 1):, :].astype(jnp.float32), "ssd": s_f}
+    if lengths is None:
+        window = hist[:, -(k - 1):, :]
+    else:  # the k-1 inputs before each row's length: hist[len : len+k-1]
+        window = jax.vmap(
+            lambda h, n: jax.lax.dynamic_slice_in_dim(h, n, k - 1, axis=0)
+        )(hist, lengths)
+    new_state = {"conv": window.astype(jnp.float32), "ssd": s_f}
     return out, new_state
 
 
@@ -201,11 +222,9 @@ def ssm_block_decode(
     z, xbc, dt_raw, (d_inner, heads, gn) = _split_proj(p, u, cfg, hybrid_branch)
     conv_in = jnp.concatenate([state["conv"].astype(xbc.dtype), xbc], axis=1)
     k = cfg.conv_kernel
-    # causal_conv convention: NEWEST sample pairs with w[:, 0] — flip w here
+    # the prefill's own conv arithmetic, over the window and the new input
     xbc_c = jax.nn.silu(
-        jnp.einsum("bkc,ck->bc", conv_in[:, -k:, :],
-                   p["conv_w"][:, ::-1].astype(xbc.dtype))
-    )[:, None, :]
+        causal_conv(xbc, p["conv_w"], state["conv"], p.get("conv_b")))
     xs, bm, cm = jnp.split(xbc_c, [d_inner, d_inner + gn], axis=-1)
     n, g = cfg.ssm_state, cfg.ssm_groups
     xh = xs.reshape(b, heads, cfg.ssm_head_dim)

@@ -118,13 +118,15 @@ class TestArchSmoke:
 
 class TestGrid:
     def test_40_cells(self):
+        """The 40 assigned cells, and granite-4.0-h-micro's four."""
         from repro.configs import all_cells
 
         cells = list(all_cells())
-        assert len(cells) == 40
+        assert len(cells) == 44
         skipped = [c for c in cells if not c[2]]
-        # 8 pure full-attention archs skip long_500k (assignment rule)
-        assert len(skipped) == 8
+        # 8 pure full-attention archs skip long_500k (assignment rule), and
+        # granite-4.0-h-micro, whose 4 attention layers are full
+        assert len(skipped) == 9
         assert all(c[1].name == "long_500k" for c in skipped)
         sub_q = {c[0].name for c in cells if c[1].name == "long_500k" and c[2]}
         assert sub_q == {"mamba2-130m", "hymba-1.5b"}
@@ -135,7 +137,7 @@ class TestGrid:
         approx = {
             "yi-9b": 8.8e9, "qwen1.5-4b": 4e9, "granite-3-2b": 2.5e9,
             "smollm-360m": 3.6e8, "mamba2-130m": 1.3e8, "hymba-1.5b": 1.5e9,
-            "dbrx-132b": 1.32e11,
+            "dbrx-132b": 1.32e11, "granite-4.0-h-micro": 3e9,
         }
         for name, target in approx.items():
             got = ARCHS[name].n_params()

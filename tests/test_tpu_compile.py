@@ -177,3 +177,19 @@ def test_step_kernel_compiles_for_v5e(
         fuse_gates=fuse_gates,
     ).compile()
     _assert_kernel(compiled)
+
+
+def test_slot_programs_compile_for_v5e_at_published_widths(topo):
+    """granite-4.0-h-micro's slot engine on one chip, at its published
+    widths with 48 slots of 2560 tokens: the longest prefill rung and the
+    decode step compile, and the weights, the pool and the larger program's
+    temporaries fit the chip's 16 GB."""
+    from repro.launch.dryrun import compile_slot_programs
+
+    mem = compile_slot_programs("granite-4.0-h-micro", topo.devices[0],
+                                rungs=(2048,))
+    assert set(mem) == {2048, "step"}
+    for m in mem.values():
+        # donated pool: the state comes back in place
+        assert m.alias_size_in_bytes > 4e9
+        assert m.argument_size_in_bytes + m.temp_size_in_bytes < 16e9
